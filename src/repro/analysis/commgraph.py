@@ -1,7 +1,7 @@
 """Comm-graph construction: classify collectives in a traced jaxpr.
 
 The walker recurses through every sub-jaxpr a container equation carries
-(``pjit``/``scan``/``remat2``/``custom_vjp``/``while``/``cond``/...), so
+(``jit``/``scan``/``remat2``/``custom_vjp``/``while``/``cond``/...), so
 collectives buried inside a remat'd layer stack under ``lax.scan`` are
 found at any depth.  Each ``shard_map`` equation is fingerprinted against
 the fused-op pattern families this repo implements:
@@ -40,7 +40,7 @@ COLLECTIVE_PRIMS = frozenset({
 })
 
 # Containers the rewriter knows how to rebuild around a rewritten site.
-REBUILDABLE_CONTAINERS = frozenset({"pjit", "scan", "remat2", "checkpoint"})
+REBUILDABLE_CONTAINERS = frozenset({"jit", "scan", "remat2", "checkpoint"})
 
 # family tags
 MATMUL_ALLREDUCE = "matmul_allreduce"
@@ -97,7 +97,7 @@ class CommGraph:
 # ---------------------------------------------------------------------------
 def sub_jaxprs(eqn) -> list:
     """Every sub-jaxpr an equation's params carry (generic: any
-    ``Jaxpr``/``ClosedJaxpr`` value, or tuple thereof — covers pjit, scan,
+    ``Jaxpr``/``ClosedJaxpr`` value, or tuple thereof — covers jit, scan,
     remat2, shard_map, cond branches, custom_vjp/jvp calls)."""
     out = []
     for v in eqn.params.values():
@@ -256,7 +256,7 @@ def _match_moe(eqn, body, ctx) -> tuple[str, dict]:
     }
 
 
-def _match_embedding(eqn, body, in_names, ctx) -> tuple[str, dict]:
+def _match_embedding(eqn, body, sharded, ctx) -> tuple[str, dict]:
     _, a2a = _first(body, "all_to_all")
     if not _a2a_layout_ok(a2a):
         return _unmatched("all_to_all layout is not the leading-axis "
@@ -267,8 +267,8 @@ def _match_embedding(eqn, body, in_names, ctx) -> tuple[str, dict]:
                           f"not the flattened world axes {world_axes}")
     if len(eqn.invars) != 2:
         return _unmatched("expected exactly (indices, tables) inputs")
-    idx_pos = next((i for i, nm in enumerate(in_names) if set(nm) == {1}), -1)
-    tab_pos = next((i for i, nm in enumerate(in_names) if set(nm) == {0}), -1)
+    idx_pos = next((i for i, d in enumerate(sharded) if d == {1}), -1)
+    tab_pos = next((i for i, d in enumerate(sharded) if d == {0}), -1)
     if idx_pos < 0 or tab_pos < 0 or idx_pos == tab_pos:
         return _unmatched("input shardings do not match the table-parallel "
                           "embedding layout")
@@ -279,7 +279,9 @@ def _classify_shard_map(eqn, ctx, containers, path) -> CollectiveSite:
     body = _body_jaxpr(eqn)
     top = Counter(e.primitive.name for e in body.eqns)
     colls, axes = _collect_collectives(body)
-    in_names = tuple(dict(n) for n in eqn.params["in_names"])
+    # per input: the dims its PartitionSpec shards over some mesh axis
+    sharded = tuple({i for i, ax in enumerate(spec) if ax is not None}
+                    for spec in eqn.params["in_specs"])
     rewritable = all(c.primitive.name in REBUILDABLE_CONTAINERS
                      for c in containers)
 
@@ -291,7 +293,7 @@ def _classify_shard_map(eqn, ctx, containers, path) -> CollectiveSite:
     elif (top.get("all_to_all") == 1 and not colls.get("dot_general")
           and "dot_general" not in top
           and len(collective_axes(body.eqns[_first(body, "all_to_all")[0]])) > 1):
-        family, detail = _match_embedding(eqn, body, in_names, ctx)
+        family, detail = _match_embedding(eqn, body, sharded, ctx)
     elif (top.get("all_gather") == 1 and top.get("dot_general") == 1
           and sum(colls.values()) == 1):
         family, detail = _match_allgather_matmul(body, ctx)

@@ -23,7 +23,7 @@ shard_map interpreting the original body with the two all_to_alls
 replaced by ``direct_all_to_all_compute``; the expert-FFN chain between
 them is re-played per destination so each output block ships the moment
 it is computed (the paper's GEMM+A2A fusion).  Containers on the path to
-a rewritten site (``scan``/``remat2``/``pjit``) are rebuilt around the
+a rewritten site (``scan``/``remat2``/``jit``) are rebuilt around the
 interpreted body; everything untouched binds verbatim.
 
 The interpreter must run under ``jax.jit`` (shard_map bodies cannot be
@@ -36,7 +36,6 @@ from typing import Any, Callable
 
 import jax
 from jax import lax
-from jax.sharding import PartitionSpec as P
 from jax._src import core as jcore
 
 from repro.analysis import commgraph as cg
@@ -350,11 +349,6 @@ class _WrapperCall:
         return [self.fn(self.fctx, *(invals[p] for p in self.arg_positions))]
 
 
-def _names_to_specs(names, avals):
-    return tuple(P(*(nm.get(i) for i in range(len(av.shape))))
-                 for nm, av in zip(names, avals))
-
-
 class _MoeRewrite:
     """Rebuild the MoE shard_map with the dispatch/combine all_to_alls
     replaced by per-destination direct sends; the FFN chain between them
@@ -368,12 +362,8 @@ class _MoeRewrite:
 
     def apply(self, invals):
         eqn = self.site.eqn
-        in_specs = _names_to_specs(
-            tuple(dict(n) for n in eqn.params["in_names"]),
-            [v.aval for v in eqn.invars])
-        out_specs = _names_to_specs(
-            tuple(dict(n) for n in eqn.params["out_names"]),
-            [v.aval for v in eqn.outvars])
+        in_specs = eqn.params["in_specs"]
+        out_specs = eqn.params["out_specs"]
         single = len(eqn.outvars) == 1
 
         def local_fn(*largs):
@@ -401,7 +391,7 @@ _SLICE_POLY = frozenset({
     "dot_general", "transpose", "broadcast_in_dim", "convert_element_type",
     "add", "sub", "mul", "div", "max", "min", "pow", "neg", "exp", "log",
     "tanh", "logistic", "sign", "integer_pow", "select_n", "custom_jvp_call",
-    "pjit",
+    "jit",
 })
 
 
@@ -445,7 +435,7 @@ def _track_through(eqns, in_dims: dict) -> "dict | None":
         elif nm == "broadcast_in_dim":
             pos, t = tracked[0]
             dims[eqn.outvars[0]] = eqn.params["broadcast_dimensions"][t]
-        elif nm in ("pjit", "custom_jvp_call"):
+        elif nm in ("jit", "custom_jvp_call"):
             sub = eqn.params.get("jaxpr", eqn.params.get("call_jaxpr"))
             sub_dims = {}
             for i, v in enumerate(eqn.invars):
@@ -504,11 +494,11 @@ def _plan_sink(body, dispatch_idx: int, combine_idx: int) -> _SinkPlan:
 
 def _replay_eqn(eqn, invals):
     """Bind one chain eqn with per-destination (size-1 tracked dim)
-    operands.  pjit/custom_jvp bodies are inlined (their stored jaxprs
+    operands.  jit/custom_jvp bodies are inlined (their stored jaxprs
     carry baked full-size avals); broadcast_in_dim re-derives its shape
     from the live operand; everything else is shape-polymorphic."""
     nm = eqn.primitive.name
-    if nm in ("pjit", "custom_jvp_call"):
+    if nm in ("jit", "custom_jvp_call"):
         sub = eqn.params.get("jaxpr", eqn.params.get("call_jaxpr"))
         return _replay_jaxpr(sub.jaxpr, sub.consts, invals)
     if nm == "broadcast_in_dim":
@@ -679,7 +669,7 @@ def plan_rewrites(graph: cg.CommGraph, ctx: ParallelContext) -> FusionPlan:
 # ---------------------------------------------------------------------------
 def _rebuild_container(eqn, invals, plan, fctx):
     nm = eqn.primitive.name
-    if nm == "pjit":
+    if nm == "jit":
         closed = eqn.params["jaxpr"]
         return _eval_jaxpr(closed.jaxpr, closed.consts, invals, plan, fctx)
     if nm in ("remat2", "checkpoint"):

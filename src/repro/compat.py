@@ -1,109 +1,23 @@
-"""Version tolerance for the jax API surface this repo targets.
+"""Repo-wide defaults for two jax entry points.
 
-The codebase is written against the current jax spelling (``jax.shard_map``
-with ``check_vma``, ``jax.sharding.AxisType``, ``pltpu.CompilerParams``,
-``lax.axis_size``).  Older jaxlibs (0.4.x) ship the same functionality
-under earlier names; everything below resolves to the native symbol when
-present and otherwise to the equivalent legacy one, so the rest of the
-repo can import from here and stay version-agnostic.
+Every ``shard_map`` in the repo runs with the per-shard value-and-mesh
+check off, and every mesh is built with ``Auto`` axis types (jax's
+default is ``Explicit``, which would turn each resharding into an
+explicit ``reshard`` the model code does not spell out).
 """
 from __future__ import annotations
 
 import jax
-from jax import lax
-from jax.experimental.pallas import tpu as pltpu
-
-_HAS_NATIVE_SHARD_MAP = hasattr(jax, "shard_map")
-if not _HAS_NATIVE_SHARD_MAP:
-    from jax.experimental.shard_map import shard_map as _legacy_shard_map
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = False):
-    """``jax.shard_map`` with the per-shard value-and-mesh check disabled.
-
-    On jax >= 0.6 this is ``jax.shard_map(..., check_vma=...)``; earlier
-    releases call it ``check_rep`` and live under ``jax.experimental``.
-    """
-    if _HAS_NATIVE_SHARD_MAP:
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    return _legacy_shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_rep=check_vma)
+    """``jax.shard_map`` with the value-and-mesh check off by default."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
 
 def make_mesh(axis_shapes, axis_names):
-    """``jax.make_mesh`` with explicit-Auto axis types where supported."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(axis_shapes, axis_names,
-                             axis_types=(axis_type.Auto,) * len(axis_names))
-    return jax.make_mesh(axis_shapes, axis_names)
-
-
-def axis_size(axis_name) -> int:
-    """Static size of a named mesh axis from inside shard_map."""
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis_name)
-    # psum of a python literal is constant-folded to the axis size (int).
-    return lax.psum(1, axis_name)
-
-
-def tpu_compiler_params(**kwargs):
-    """``pltpu.CompilerParams`` (renamed from ``TPUCompilerParams``)."""
-    cls = getattr(pltpu, "CompilerParams", None)
-    if cls is None:
-        cls = pltpu.TPUCompilerParams
-    return cls(**kwargs)
-
-
-def tree_flatten_with_path(tree):
-    """``jax.tree.flatten_with_path`` (older: ``jax.tree_util``)."""
-    if hasattr(jax.tree, "flatten_with_path"):
-        return jax.tree.flatten_with_path(tree)
-    return jax.tree_util.tree_flatten_with_path(tree)
-
-
-_BARRIER_DIFFERENTIABLE: bool | None = None
-
-
-def _barrier_differentiable() -> bool:
-    """Whether ``lax.optimization_barrier`` has a differentiation rule
-    (absent on older jax; probed once with an abstract trace)."""
-    global _BARRIER_DIFFERENTIABLE
-    if _BARRIER_DIFFERENTIABLE is None:
-        import jax.numpy as jnp
-
-        try:
-            jax.eval_shape(jax.grad(lambda x: lax.optimization_barrier(x)),
-                           jnp.float32(0.0))
-            _BARRIER_DIFFERENTIABLE = True
-        except NotImplementedError:
-            _BARRIER_DIFFERENTIABLE = False
-    return _BARRIER_DIFFERENTIABLE
-
-
-@jax.custom_vjp
-def _barrier_vjp(x):
-    return lax.optimization_barrier(x)
-
-
-def _barrier_fwd(x):
-    return _barrier_vjp(x), None
-
-
-def _barrier_bwd(_, g):
-    return (lax.optimization_barrier(g),)
-
-
-_barrier_vjp.defvjp(_barrier_fwd, _barrier_bwd)
-
-
-def optimization_barrier(x):
-    """Differentiable ``lax.optimization_barrier``.
-
-    New jax ships a native differentiation rule; older releases get a
-    custom-vjp wrapper whose cotangent passes through its own barrier (the
-    barrier is semantically the identity, so this is exact)."""
-    if _barrier_differentiable():
-        return lax.optimization_barrier(x)
-    return _barrier_vjp(x)
+    """``jax.make_mesh`` with ``Auto`` axis types."""
+    return jax.make_mesh(
+        axis_shapes, axis_names,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names))

@@ -1,1 +1,2 @@
-from repro.core import fused  # noqa: F401
+"""Fused compute-collective operators; the public API is
+:mod:`repro.core.fused`."""

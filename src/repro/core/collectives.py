@@ -20,7 +20,6 @@ import jax.numpy as jnp
 from jax import lax
 
 from repro.core.scheduling import ring_offsets, sub_chunk_service_order
-from repro.compat import axis_size, optimization_barrier
 
 
 def _ring_perm(n: int, shift: int = 1):
@@ -61,7 +60,7 @@ def ring_permute(x, axis_name: str, n: int, shift: int = 1):
     the wire.  Accepts a pytree payload (the fp8 wire format rides a
     ``(values, scale)`` pair), barriering and permuting every leaf."""
     return jax.tree.map(
-        lambda leaf: lax.ppermute(optimization_barrier(_wire_fault(leaf)),
+        lambda leaf: lax.ppermute(lax.optimization_barrier(_wire_fault(leaf)),
                                   axis_name, _ring_perm(n, shift)), x)
 
 
@@ -135,7 +134,7 @@ def all_gather_wire(x, axis_name: str, n: int, *, axis: int = 0,
     p = wire_cast(x, wire)
     if isinstance(p, tuple):
         q, scale = p
-        qg = lax.all_gather(optimization_barrier(_wire_fault(q)), axis_name,
+        qg = lax.all_gather(lax.optimization_barrier(_wire_fault(q)), axis_name,
                             axis=0,
                             tiled=False)          # [n, ...chunk]
         sg = lax.all_gather(scale, axis_name, axis=0, tiled=False)  # [n, 1]
@@ -144,7 +143,7 @@ def all_gather_wire(x, axis_name: str, n: int, *, axis: int = 0,
         parts = [lax.index_in_dim(vals, s, axis=0, keepdims=False)
                  for s in range(n)]
         return jnp.concatenate(parts, axis=axis).astype(x.dtype)
-    g = lax.all_gather(optimization_barrier(_wire_fault(p)), axis_name,
+    g = lax.all_gather(lax.optimization_barrier(_wire_fault(p)), axis_name,
                        axis=axis, tiled=True)
     return g.astype(x.dtype)
 
@@ -227,7 +226,7 @@ def ring_reduce_scatter_compute(
     CoCoNet.  ``wire="f32"`` keeps the pre-wire graph bit-identical
     (payloads travel at the compute dtype, partials accumulate in it).
     """
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     d = lax.axis_index(axis_name)
     q = chunks_per_rank
     order = sub_chunk_service_order(q, skew)
@@ -309,7 +308,7 @@ def ring_all_gather_compute(
     exactly once regardless of hop count); the local shard is consumed
     uncompressed.  ``wire="f32"`` is the exact pre-wire path.
     """
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     d = lax.axis_index(axis_name)
     acc = consume_fn(d, x_local, out_init)
     buf = wire_cast(x_local, wire) if wire not in (None, "f32") else x_local
@@ -364,7 +363,7 @@ def direct_all_to_all_compute(
     rounds exactly once.  The locally-consumed chunk never touches the
     wire and stays exact; ``wire="f32"`` is the exact pre-wire path.
     """
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     d = lax.axis_index(axis_name)
     q = chunks_per_rank
     chunk_shape = tuple(out_shape_dtype.shape)

@@ -37,7 +37,7 @@ from repro.core.collectives import (all_gather_wire,
                                     ring_reduce_scatter_compute)
 from repro.core.degrade import degrade_mode
 from repro.parallel.sharding import ParallelContext
-from repro.compat import axis_size, shard_map
+from repro.compat import shard_map
 
 
 def _bulk(xl, wl, axis):
@@ -45,7 +45,7 @@ def _bulk(xl, wl, axis):
 
 
 def _fused_rows(xl, wl, axis, schedule, q, skew, wire):
-    n = axis_size(axis)
+    n = lax.axis_size(axis)
     chunk = xl.shape[0] // (n * q)
 
     def partial(f):
@@ -59,7 +59,7 @@ def _fused_rows(xl, wl, axis, schedule, q, skew, wire):
 
 
 def _fused_cols(xl, wl, axis, schedule, q, skew, wire):
-    n = axis_size(axis)
+    n = lax.axis_size(axis)
     chunk = wl.shape[1] // (n * q)
 
     def partial(f):

@@ -5,8 +5,8 @@ Each kernel package ships three modules:
   ops.py    - jit'd public wrapper (shape checks, dtype policy, vmap rules)
   ref.py    - pure-jnp oracle used by the allclose test sweeps
 
-``interpret=True`` (CPU) is used for validation; on TPU the same calls
-lower to Mosaic.  The fused_* kernels use device-initiated remote DMA
+Off the TPU the kernels run in the Pallas interpreter (validation); on
+the TPU the same calls lower to Mosaic (:func:`resolve_interpret`).  The fused_* kernels use device-initiated remote DMA
 (pltpu.make_async_remote_copy) — the TPU analogue of the paper's
 GPU-initiated RDMA PUTs.
 
@@ -24,6 +24,14 @@ def interpret_mode() -> bool:
     import jax
 
     return jax.default_backend() != "tpu"
+
+
+def resolve_interpret(interpret: bool | None) -> bool:
+    """A kernel entry's ``interpret`` argument: ``None`` (every entry's
+    default) means the interpreter exactly when the default backend is
+    not a TPU; a caller that compiles for a described chip passes
+    ``False``."""
+    return interpret_mode() if interpret is None else interpret
 
 
 _FP8_CLAMP_WARNED: set = set()

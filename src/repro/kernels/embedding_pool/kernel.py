@@ -14,7 +14,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-from repro.compat import tpu_compiler_params
+
+from repro.kernels import resolve_interpret
 
 
 def _pool_kernel(idx_ref, row_ref, o_ref, acc_ref):
@@ -33,7 +34,7 @@ def _pool_kernel(idx_ref, row_ref, o_ref, acc_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def embedding_pool_pallas(table, idx, *, interpret=True):
+def embedding_pool_pallas(table, idx, *, interpret=None):
     """table: [V, D]; idx: [B, L] int32 -> mean-pooled [B, D]."""
     v, d = table.shape
     b, L = idx.shape
@@ -50,7 +51,7 @@ def embedding_pool_pallas(table, idx, *, interpret=True):
         _pool_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, d), table.dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(idx, table)

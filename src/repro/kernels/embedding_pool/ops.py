@@ -1,10 +1,9 @@
 """Public embedding-pool wrapper."""
 from __future__ import annotations
 
-from repro.kernels import interpret_mode
 from repro.kernels.embedding_pool.kernel import embedding_pool_pallas
 
 
 def embedding_pool(table, idx):
     """table: [V, D]; idx: [B, L] -> [B, D] mean-pooled bags."""
-    return embedding_pool_pallas(table, idx, interpret=interpret_mode())
+    return embedding_pool_pallas(table, idx)

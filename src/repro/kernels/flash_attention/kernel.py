@@ -14,7 +14,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-from repro.compat import tpu_compiler_params
+
+from repro.kernels import resolve_interpret
 
 NEG_INF = -1e30
 
@@ -61,7 +62,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 @functools.partial(jax.jit, static_argnames=("scale", "causal", "bq", "bkv",
                                              "interpret"))
 def flash_attention_pallas(q, k, v, *, scale, causal=True, bq=128, bkv=128,
-                           interpret=True):
+                           interpret=None):
     """q,k,v: [BH, S, d] (heads pre-folded into batch) -> [BH, S, d]."""
     bh, s, d = q.shape
     assert s % bq == 0 and s % bkv == 0, (s, bq, bkv)
@@ -82,7 +83,7 @@ def flash_attention_pallas(q, k, v, *, scale, causal=True, bq=128, bkv=128,
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, d), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, k, v)

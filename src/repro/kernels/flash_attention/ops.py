@@ -3,7 +3,6 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
-from repro.kernels import interpret_mode
 from repro.kernels.flash_attention.kernel import flash_attention_pallas
 
 
@@ -21,5 +20,5 @@ def flash_attention(q, k, v, *, scale=None, causal=True, bq=128, bkv=128):
     fold = lambda t: t.transpose(0, 2, 1, 3).reshape(b * h, s, d)
     out = flash_attention_pallas(
         fold(q), fold(k), fold(v), scale=scale, causal=causal,
-        bq=_block(s, bq), bkv=_block(s, bkv), interpret=interpret_mode())
+        bq=_block(s, bq), bkv=_block(s, bkv))
     return out.reshape(b, h, s, d).transpose(0, 2, 1, 3)

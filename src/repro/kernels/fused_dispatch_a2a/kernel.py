@@ -36,14 +36,14 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import tpu_compiler_params
-from repro.kernels.tile_pipeline import (ANY, drain, remote_tile_put,
+from repro.kernels import resolve_interpret
+from repro.kernels.tile_pipeline import (drain, entry_barrier, remote_tile_put,
                                          step_schedule, stream_block_copy)
 
 
 def _dispatch_a2a_kernel(ids_ref, x_hbm, o_ref, x_slots, x_sems, tx_ref,
                          rx_ref, send_sem, recv_sem, *, n_dev, q, sub,
-                         axis_name, id_style, use_rx):
+                         axis_name, id_style, use_rx, barrier):
     my = ids_ref[0]
     base = ids_ref[1]
     i = pl.program_id(0)
@@ -58,6 +58,9 @@ def _dispatch_a2a_kernel(ids_ref, x_hbm, o_ref, x_slots, x_sems, tx_ref,
 
     @pl.when(i == 0)
     def _():
+        if barrier:
+            # no PUT may land before every peer runs this kernel
+            entry_barrier(my, n_dev, axis_name, id_style, base)
         xdma(0, 0).start()
 
     @pl.when((s_i == 0) & (i + q < n_steps))
@@ -71,9 +74,8 @@ def _dispatch_a2a_kernel(ids_ref, x_hbm, o_ref, x_slots, x_sems, tx_ref,
 
     off = step_off(i)
     dest = lax.rem(my + off, n_dev)
-    c0 = s_i * sub
-    xs = x_slots[lax.rem(blk, 2)]                     # [B, E, C, D]
-    chunk = lax.dynamic_slice_in_dim(xs, c0, sub, axis=2)
+    c0 = s_i * sub if q > 1 else 0
+    chunk = x_slots[lax.rem(blk, 2), :, :, pl.ds(c0, sub)]  # [B, E, sub, D]
 
     # receive target: the output ref itself (zero-copy) at the exact wire,
     # a wire-dtype rx staging ref otherwise (upcast at the end)
@@ -118,7 +120,7 @@ def _dispatch_a2a_kernel(ids_ref, x_hbm, o_ref, x_slots, x_sems, tx_ref,
                                     "axis_name", "id_style", "wire"))
 def fused_dispatch_a2a_pallas(xt, my_ep, ring_base, *, n_dev, axis_name,
                               comm_aware=True, chunks_per_rank=1, skew=0,
-                              collective_id=10, interpret=True,
+                              collective_id=10, interpret=None,
                               id_style=None, wire="f32"):
     """Per-shard device-initiated dispatch All-to-All.
 
@@ -135,7 +137,11 @@ def fused_dispatch_a2a_pallas(xt, my_ep, ring_base, *, n_dev, axis_name,
     order (Fig. 14).  ``wire`` is the PUT payload dtype — supported
     ``{"f32", "bf16"}`` (fp8 per-chunk scaling is an XLA-path feature;
     callers clamp).
+
+    ``interpret=None`` runs the Pallas interpreter exactly when the
+    default backend is not a TPU (:func:`repro.kernels.resolve_interpret`).
     """
+    interpret = resolve_interpret(interpret)
     if id_style is None:
         id_style = "logical" if interpret else "mesh"
     if wire not in ("f32", "bf16"):
@@ -153,12 +159,13 @@ def fused_dispatch_a2a_pallas(xt, my_ep, ring_base, *, n_dev, axis_name,
     use_rx = wire_dt != xt.dtype
     kernel = functools.partial(_dispatch_a2a_kernel, n_dev=n_dev, q=q,
                                sub=sub, axis_name=axis_name,
-                               id_style=id_style, use_rx=use_rx)
+                               id_style=id_style, use_rx=use_rx,
+                               barrier=not interpret)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(n_steps,),
         in_specs=[
-            pl.BlockSpec(memory_space=ANY),           # token blocks in HBM
+            pl.BlockSpec(memory_space=pl.ANY),        # token blocks in HBM
         ],
         out_specs=pl.BlockSpec((nd, b, e, c, d), lambda i, s: (0,) * 5),
         scratch_shapes=[
@@ -183,6 +190,6 @@ def fused_dispatch_a2a_pallas(xt, my_ep, ring_base, *, n_dev, axis_name,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((nd, b, e, c, d), xt.dtype),
-        compiler_params=tpu_compiler_params(collective_id=collective_id),
+        compiler_params=pltpu.CompilerParams(collective_id=collective_id),
         interpret=interpret,
     )(ids, xt)

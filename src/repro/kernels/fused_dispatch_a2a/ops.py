@@ -6,7 +6,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import axis_size, shard_map
+from repro.compat import shard_map
 from repro.core.collectives import feasible_chunks_per_rank
 from repro.kernels import clamp_kernel_wire, interpret_mode
 from repro.kernels.flatmesh import (WORLD_AXIS, flat_world_mesh,
@@ -40,7 +40,7 @@ def fused_dispatch_a2a_shard(xt, axis, *, comm_aware=True, chunks_per_rank=1,
     is the same exchange applied to the cotangent.
     """
     wire = clamp_kernel_wire(wire, "fused_dispatch_a2a")
-    world = axis_size(axis)
+    world = lax.axis_size(axis)
     n_dev = world if ring_size is None else int(ring_size)
     q = feasible_chunks_per_rank(xt.shape[3], 1, chunks_per_rank)
 
@@ -53,7 +53,7 @@ def fused_dispatch_a2a_shard(xt, axis, *, comm_aware=True, chunks_per_rank=1,
         base = my_world - my
         return fused_dispatch_a2a_pallas(
             v, my, base, n_dev=n_dev, axis_name=axis, comm_aware=comm_aware,
-            chunks_per_rank=q, skew=skew, interpret=interpret_mode(),
+            chunks_per_rank=q, skew=skew,
             wire=wire)
 
     @jax.custom_vjp

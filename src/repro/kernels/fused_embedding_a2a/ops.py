@@ -9,7 +9,7 @@ from jax.sharding import PartitionSpec as P
 from repro.kernels import interpret_mode
 from repro.kernels.fused_embedding_a2a.kernel import fused_embedding_a2a_pallas
 from repro.parallel.sharding import ParallelContext
-from repro.compat import axis_size, shard_map
+from repro.compat import shard_map
 
 
 def fused_embedding_a2a_kernel_available(mesh=None) -> bool:
@@ -28,10 +28,10 @@ def fused_embedding_a2a(ctx: ParallelContext, indices, tables, *,
 
     def local_fn(idx_l, tab_l):
         my = lax.axis_index(axis)
-        n = axis_size(axis)
+        n = lax.axis_size(axis)
         return fused_embedding_a2a_pallas(
             tab_l, idx_l, my, n_dev=n, L=L, axis_name=axis,
-            comm_aware=comm_aware, interpret=interpret_mode())
+            comm_aware=comm_aware)
 
     return shard_map(
         local_fn, mesh=ctx.mesh,

@@ -38,8 +38,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import tpu_compiler_params
-from repro.kernels.tile_pipeline import (ANY, drain, remote_tile_put,
+from repro.kernels import resolve_interpret
+from repro.kernels.tile_pipeline import (drain, entry_barrier, remote_tile_put,
                                          step_schedule, stream_block_copy)
 
 
@@ -68,7 +68,7 @@ def _gemm_a2a_kernel(ids_ref, x_hbm, wu_hbm, wg_hbm, wd_hbm, o_ref,
                      x_slots, x_sems, wu_slots, wu_sems, wg_slots, wg_sems,
                      wd_slots, wd_sems, tx_ref, rx_ref, send_sem, recv_sem, *,
                      n_dev, e_loc, tile_k, tile_f, dm, f, act,
-                     axis_name, id_style, use_rx):
+                     axis_name, id_style, use_rx, barrier):
     my = ids_ref[0]
     base = ids_ref[1]
     i = pl.program_id(0)
@@ -104,6 +104,9 @@ def _gemm_a2a_kernel(ids_ref, x_hbm, wu_hbm, wg_hbm, wd_hbm, o_ref,
 
     @pl.when(i == 0)
     def _():
+        if barrier:
+            # no PUT may land before every peer runs this kernel
+            entry_barrier(my, n_dev, axis_name, id_style, base)
         xdma(0, 0).start()
 
     @pl.when(i + 1 < n_dev)
@@ -193,7 +196,7 @@ def _gemm_a2a_kernel(ids_ref, x_hbm, wu_hbm, wg_hbm, wd_hbm, o_ref,
                                     "tile_f", "wire"))
 def fused_gemm_a2a_pallas(xt, w_up, w_gate, w_down, my_ep, ring_base, *,
                           n_dev, axis_name, act, comm_aware=True, skew=0,
-                          collective_id=8, interpret=True, id_style=None,
+                          collective_id=8, interpret=None, id_style=None,
                           tile_k=None, tile_f=None, wire="f32"):
     """Per-shard fused expert FFN + combine All-to-All.
 
@@ -218,7 +221,11 @@ def fused_gemm_a2a_pallas(xt, w_up, w_gate, w_down, my_ep, ring_base, *,
     — the remote DMA moves half the bytes at the cost of the receive-side
     zero-copy.  Supported: ``{"f32", "bf16"}`` (fp8 per-chunk scaling is
     an XLA-path feature; callers clamp).
+
+    ``interpret=None`` runs the Pallas interpreter exactly when the
+    default backend is not a TPU (:func:`repro.kernels.resolve_interpret`).
     """
+    interpret = resolve_interpret(interpret)
     if id_style is None:
         id_style = "logical" if interpret else "mesh"
     if wire not in ("f32", "bf16"):
@@ -235,15 +242,16 @@ def fused_gemm_a2a_pallas(xt, w_up, w_gate, w_down, my_ep, ring_base, *,
     kernel = functools.partial(_gemm_a2a_kernel, n_dev=n_dev, e_loc=e,
                                tile_k=tile_k, tile_f=tile_f, dm=d, f=f,
                                act=act, axis_name=axis_name,
-                               id_style=id_style, use_rx=use_rx)
+                               id_style=id_style, use_rx=use_rx,
+                               barrier=not interpret)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(n_dev,),
         in_specs=[
-            pl.BlockSpec(memory_space=ANY),           # token blocks in HBM
-            pl.BlockSpec(memory_space=ANY),           # w_up in HBM
-            pl.BlockSpec(memory_space=ANY),           # w_gate in HBM
-            pl.BlockSpec(memory_space=ANY),           # w_down in HBM
+            pl.BlockSpec(memory_space=pl.ANY),        # token blocks in HBM
+            pl.BlockSpec(memory_space=pl.ANY),        # w_up in HBM
+            pl.BlockSpec(memory_space=pl.ANY),        # w_gate in HBM
+            pl.BlockSpec(memory_space=pl.ANY),        # w_down in HBM
         ],
         out_specs=pl.BlockSpec((nd, b, e, c, d), lambda i, s: (0,) * 5),
         scratch_shapes=[
@@ -274,6 +282,6 @@ def fused_gemm_a2a_pallas(xt, w_up, w_gate, w_down, my_ep, ring_base, *,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((nd, b, e, c, d), xt.dtype),
-        compiler_params=tpu_compiler_params(collective_id=collective_id),
+        compiler_params=pltpu.CompilerParams(collective_id=collective_id),
         interpret=interpret,
     )(ids, xt, w_up, w_gate, w_down)

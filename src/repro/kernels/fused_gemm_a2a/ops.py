@@ -13,7 +13,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import axis_size, shard_map
+from repro.compat import shard_map
 from repro.kernels import clamp_kernel_wire, interpret_mode
 from repro.kernels.flatmesh import (WORLD_AXIS, flat_world_mesh,
                                     moe_from_world, moe_to_world,
@@ -37,7 +37,7 @@ def fused_gemm_a2a_kernel_available(mesh=None) -> bool:
 def _ring_position(axis, ring_size):
     """(n_dev, my, base) for a PUT ring over ``axis`` — the whole axis by
     default, or contiguous ``ring_size`` groups of a flattened world."""
-    world = axis_size(axis)
+    world = lax.axis_size(axis)
     n_dev = world if ring_size is None else int(ring_size)
     my_world = lax.axis_index(axis)
     my = lax.rem(my_world, n_dev)
@@ -68,7 +68,7 @@ def fused_gemm_a2a_shard(xt, w_up, w_gate, w_down, axis, *, act,
         n_dev, my, base = _ring_position(axis, ring_size)
         return fused_gemm_a2a_pallas(
             v, wu, wg, wd, my, base, n_dev=n_dev, axis_name=axis, act=act,
-            comm_aware=comm_aware, skew=skew, interpret=interpret_mode(),
+            comm_aware=comm_aware, skew=skew,
             tile_k=tile_k, tile_f=tile_f, wire=wire)
 
     def ref_call(v, wu, wg, wd):

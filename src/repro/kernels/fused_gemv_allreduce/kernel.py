@@ -49,9 +49,9 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import tpu_compiler_params
 from repro.core.autotune import choose_tile_k, choose_tile_n, feasible_tile
-from repro.kernels.tile_pipeline import (ANY, drain, neighbor_barrier,
+from repro.kernels import resolve_interpret
+from repro.kernels.tile_pipeline import (drain, entry_barrier,
                                          remote_tile_put, step_schedule,
                                          stream_tile_copy)
 
@@ -112,8 +112,8 @@ def _fused_kernel(ids_ref, x_ref, w_hbm, o_ref,
     @pl.when(i == 0)
     def _():
         if barrier:
-            # sync ring neighbours before touching symmetric buffers
-            neighbor_barrier(my, n_dev, axis_name, id_style)
+            # no PUT may land before every peer runs this kernel
+            entry_barrier(my, n_dev, axis_name, id_style)
         # step 0 is panel 0 of the first tile — ragged only if k_panels==1,
         # which implies tile_k == K and k_rem == tile_k (never ragged)
         wdma(jnp.int32(0), False).start()
@@ -213,13 +213,13 @@ def _fused_kernel(ids_ref, x_ref, w_hbm, o_ref,
 
 @functools.partial(jax.jit,
                    static_argnames=("n_dev", "comm_aware", "collective_id",
-                                    "barrier", "interpret", "axis_name",
+                                    "interpret", "axis_name",
                                     "id_style", "tile_n", "tile_k",
                                     "vmem_budget_bytes", "wire"))
 def fused_matmul_allreduce_pallas(x, w, my_tp, *, n_dev, axis_name,
                                   comm_aware=True, collective_id=7,
-                                  barrier=False, interpret=True,
-                                  id_style=None, tile_n=None, tile_k=None,
+                                  interpret=None, id_style=None,
+                                  tile_n=None, tile_k=None,
                                   vmem_budget_bytes=8 << 20, wire="f32"):
     """Per-shard tile-pipelined fused GEMV/GEMM+AllReduce.
 
@@ -241,7 +241,11 @@ def fused_matmul_allreduce_pallas(x, w, my_tp, *, n_dev, axis_name,
     ``{"f32", "bf16"}`` — the fp8 per-chunk-scale format is an XLA-path
     feature (callers clamp).  The phase-2 broadcast ships final outputs
     and stays at the output dtype.
+
+    ``interpret=None`` runs the Pallas interpreter exactly when the
+    default backend is not a TPU (:func:`repro.kernels.resolve_interpret`).
     """
+    interpret = resolve_interpret(interpret)
     if id_style is None:
         id_style = "logical" if interpret else "mesh"
     if wire not in ("f32", "bf16"):
@@ -274,14 +278,14 @@ def fused_matmul_allreduce_pallas(x, w, my_tp, *, n_dev, axis_name,
     kernel = functools.partial(_fused_kernel, n_dev=n_dev,
                                tiles_per_rank=tiles_per_rank, tile_n=tile_n,
                                tile_k=tile_k, k_panels=k_panels, k_rem=k_rem,
-                               barrier=barrier,
+                               barrier=not interpret,
                                axis_name=axis_name, id_style=id_style)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(num_tiles * k_panels,),
         in_specs=[
             pl.BlockSpec((b, k), lambda i, s: (0, 0)),
-            pl.BlockSpec(memory_space=ANY),           # w stays in HBM
+            pl.BlockSpec(memory_space=pl.ANY),        # w stays in HBM
         ],
         out_specs=pl.BlockSpec((b, n), lambda i, s: (0, 0)),
         scratch_shapes=[
@@ -311,6 +315,6 @@ def fused_matmul_allreduce_pallas(x, w, my_tp, *, n_dev, axis_name,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, n), x.dtype),
-        compiler_params=tpu_compiler_params(collective_id=collective_id),
+        compiler_params=pltpu.CompilerParams(collective_id=collective_id),
         interpret=interpret,
     )(ids, x, w)

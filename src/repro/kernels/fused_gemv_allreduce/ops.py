@@ -9,7 +9,7 @@ from jax.sharding import PartitionSpec as P
 from repro.kernels import interpret_mode
 from repro.kernels.fused_gemv_allreduce.kernel import fused_matmul_allreduce_pallas
 from repro.parallel.sharding import ParallelContext
-from repro.compat import axis_size, shard_map
+from repro.compat import shard_map
 
 
 def fused_matmul_allreduce_kernel_available(mesh=None) -> bool:
@@ -32,12 +32,12 @@ def fused_matmul_allreduce_shard(xl, wl, axis, *, comm_aware=True,
     ragged final K panel).  ``wire`` compresses the phase-1 PUT payload
     (kernel path supports f32/bf16; fp8 is clamped to bf16 — the
     per-chunk-scale format is an XLA-path feature)."""
-    n_dev = axis_size(axis)
+    n_dev = lax.axis_size(axis)
     my = lax.axis_index(axis)
     wire = "bf16" if wire == "fp8" else wire
     return fused_matmul_allreduce_pallas(
         xl, wl, my, n_dev=n_dev, axis_name=axis, comm_aware=comm_aware,
-        interpret=interpret_mode(), tile_n=tile_n, tile_k=tile_k,
+        tile_n=tile_n, tile_k=tile_k,
         vmem_budget_bytes=vmem_budget_bytes, wire=wire)
 
 

@@ -13,7 +13,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-from repro.compat import tpu_compiler_params
+
+from repro.kernels import resolve_interpret
 
 
 def _gemm_kernel(x_ref, w_ref, o_ref, acc_ref):
@@ -30,7 +31,7 @@ def _gemm_kernel(x_ref, w_ref, o_ref, acc_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret"))
-def gemm_pallas(x, w, *, bm=128, bn=128, bk=128, interpret=True):
+def gemm_pallas(x, w, *, bm=128, bn=128, bk=128, interpret=None):
     m, k = x.shape
     k2, n = w.shape
     assert k == k2 and m % bm == 0 and n % bn == 0 and k % bk == 0, (x.shape, w.shape, bm, bn, bk)
@@ -44,7 +45,7 @@ def gemm_pallas(x, w, *, bm=128, bn=128, bk=128, interpret=True):
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, l: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x, w)

@@ -3,7 +3,6 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
-from repro.kernels import interpret_mode
 from repro.kernels.gemm.kernel import gemm_pallas
 
 
@@ -18,4 +17,4 @@ def gemm(x, w, *, bm=128, bn=128, bk=128):
     m, k = x.shape
     _, n = w.shape
     bm, bn, bk = _block(m, bm), _block(n, bn), _block(k, bk)
-    return gemm_pallas(x, w, bm=bm, bn=bn, bk=bk, interpret=interpret_mode())
+    return gemm_pallas(x, w, bm=bm, bn=bn, bk=bk)

@@ -13,7 +13,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-from repro.compat import tpu_compiler_params
+
+from repro.kernels import resolve_interpret
 
 
 def _gemv_kernel(x_ref, w_ref, o_ref, acc_ref):
@@ -30,7 +31,7 @@ def _gemv_kernel(x_ref, w_ref, o_ref, acc_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("bn", "bk", "interpret"))
-def gemv_pallas(x, w, *, bn=256, bk=512, interpret=True):
+def gemv_pallas(x, w, *, bn=256, bk=512, interpret=None):
     (b, k), (k2, n) = x.shape, w.shape
     assert k == k2 and n % bn == 0 and k % bk == 0, (x.shape, w.shape, bn, bk)
     return pl.pallas_call(
@@ -43,7 +44,7 @@ def gemv_pallas(x, w, *, bn=256, bk=512, interpret=True):
         out_specs=pl.BlockSpec((b, bn), lambda j, l: (0, j)),
         out_shape=jax.ShapeDtypeStruct((b, n), x.dtype),
         scratch_shapes=[pltpu.VMEM((b, bn), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x, w)

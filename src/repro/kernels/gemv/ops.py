@@ -3,7 +3,6 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
-from repro.kernels import interpret_mode
 from repro.kernels.gemv.kernel import gemv_pallas
 
 
@@ -20,6 +19,5 @@ def gemv(x, w, *, bn=256, bk=512):
     if squeeze:
         x = x[None]
     k, n = w.shape
-    out = gemv_pallas(x, w, bn=_block(n, bn), bk=_block(k, bk),
-                      interpret=interpret_mode())
+    out = gemv_pallas(x, w, bn=_block(n, bn), bk=_block(k, bk))
     return out[0] if squeeze else out

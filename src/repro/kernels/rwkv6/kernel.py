@@ -14,7 +14,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-from repro.compat import tpu_compiler_params
+
+from repro.kernels import resolve_interpret
 
 
 def _wkv6_kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, o_ref, state_ref, *, chunk):
@@ -53,7 +54,7 @@ def _wkv6_kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, o_ref, state_ref, *, chunk)
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
-def wkv6_pallas(r, k, v, lw, u, *, chunk=32, interpret=True):
+def wkv6_pallas(r, k, v, lw, u, *, chunk=32, interpret=None):
     """r,k,v,lw: [BH, T, N] (heads folded into batch; lw = log decay);
     u: [BH, 1, N] bonus.  Returns o: [BH, T, N] f32."""
     bh, t, n = r.shape
@@ -72,7 +73,7 @@ def wkv6_pallas(r, k, v, lw, u, *, chunk=32, interpret=True):
         out_specs=pl.BlockSpec((1, chunk, n), lambda b, i: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, t, n), jnp.float32),
         scratch_shapes=[pltpu.VMEM((n, n), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(r, k, v, lw, u)

@@ -3,7 +3,6 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
-from repro.kernels import interpret_mode
 from repro.kernels.rwkv6.kernel import wkv6_pallas
 
 
@@ -15,5 +14,5 @@ def wkv6(r, k, v, w, u, *, chunk=32):
     uu = jnp.broadcast_to(u[None], (b, h, n)).reshape(b * h, 1, n)
     o = wkv6_pallas(fold(r).astype(jnp.float32), fold(k).astype(jnp.float32),
                     fold(v).astype(jnp.float32), fold(lw), uu,
-                    chunk=min(chunk, t), interpret=interpret_mode())
+                    chunk=min(chunk, t))
     return o.reshape(b, h, t, n).transpose(0, 2, 1, 3)

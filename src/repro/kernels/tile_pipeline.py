@@ -22,14 +22,9 @@ device-initiated paths share one schedule definition.
 """
 from __future__ import annotations
 
-import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-ANY = getattr(pltpu, "ANY", None)
-if ANY is None:  # older spelling
-    ANY = pltpu.TPUMemorySpace.ANY
 
 
 def device_id_pair(dest, axis_name: str, id_style: str):
@@ -41,13 +36,24 @@ def device_id_pair(dest, axis_name: str, id_style: str):
     return dest, pltpu.DeviceIdType.LOGICAL
 
 
-def neighbor_barrier(my, n_dev: int, axis_name: str, id_style: str):
-    """Sync both ring neighbours before touching symmetric buffers."""
+def entry_barrier(my, n_dev: int, axis_name: str, id_style: str, base=0):
+    """Wait until every peer of the ring has entered the kernel.
+
+    A PUT lands in the peer's VMEM scratch, which is the peer's own only
+    once the peer runs this kernel; before that the same VMEM may belong
+    to whatever ran there last.  So each rank signals the barrier
+    semaphore of every peer it will PUT to and waits for all of them to
+    signal back before its first remote write.  ``base`` is the id of
+    ring position 0 (non-zero only on a flattened multi-axis world).
+    The kernel's ``collective_id`` names the semaphore.  Kernels call
+    this under Mosaic only: the interpreter steps all ranks in lock step
+    and has no barrier semaphore."""
     bsem = pltpu.get_barrier_semaphore()
-    for nb in (lax.rem(my + n_dev - 1, n_dev), lax.rem(my + 1, n_dev)):
-        did, dt = device_id_pair(nb, axis_name, id_style)
+    for off in range(1, n_dev):
+        did, dt = device_id_pair(base + lax.rem(my + off, n_dev), axis_name,
+                                 id_style)
         pltpu.semaphore_signal(bsem, device_id=did, device_id_type=dt)
-    pltpu.semaphore_wait(bsem, 2)
+    pltpu.semaphore_wait(bsem, n_dev - 1)
 
 
 def stream_tile_copy(hbm_ref, vmem_slots, sems, slot, col_start, tile_n,

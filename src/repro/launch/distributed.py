@@ -1,12 +1,14 @@
-"""Multi-host initialization for real TPU pods (and the CPU test lane).
+"""Multi-host initialization for TPU pods (and the CPU test lane).
 
-On a v5e pod slice every host runs the same binary;
-``jax.distributed.initialize()`` wires the hosts together (coordinator
-from the TPU metadata on GCP, or explicit addresses elsewhere).  After
-init, ``jax.devices()`` spans the slice and `make_production_mesh()`
+On a multi-host v5e slice every host runs the same binary with an
+explicit coordinator address, world size and rank (flags or the
+``JAX_COORDINATOR_ADDRESS``/``JAX_NUM_PROCESSES``/``JAX_PROCESS_ID``
+environment); ``jax.distributed.initialize`` then wires the hosts
+together, ``jax.devices()`` spans the slice and `make_production_mesh()`
 builds the global mesh exactly as the dry-run proved it.  The same entry
 point wires the multi-process CPU lane (:mod:`repro.runtime.
-multiprocess`), which passes explicit coordinator/world/rank.
+multiprocess`).  A single-host run (one chip, or the four chips of one
+v5e host) configures nothing and initializes nothing.
 """
 from __future__ import annotations
 
@@ -37,17 +39,18 @@ def initialize_distributed(coordinator: str | None = None,
     """Idempotent multi-host init.  Returns True when this call (or an
     earlier one) actually initialized the distributed runtime.
 
-    On GCP TPU VMs all arguments are discovered from the metadata server;
-    elsewhere pass coordinator ("host:port"), num_processes, process_id
-    (or set JAX_COORDINATOR_ADDRESS / JAX_NUM_PROCESSES / JAX_PROCESS_ID).
+    The runtime is initialized only when a coordinator is configured:
+    pass coordinator ("host:port"), num_processes and process_id, or set
+    JAX_COORDINATOR_ADDRESS / JAX_NUM_PROCESSES / JAX_PROCESS_ID.  With
+    none of them this is single-process mode and nothing is initialized
+    — never cluster auto-detection, which on a machine without a
+    metadata server can hang or build a wrong world.
 
-    Failure policy: with an explicit coordinator (argument or env var),
-    any failure is a genuine misconfiguration — bad address, port in
-    use, a peer missing — and **propagates**; silently degrading a
-    configured multi-host run to single-host mode would train on 1/Nth
-    of the data while looking healthy.  Only the known "nothing
-    configured, auto-detection found nothing" case falls back to
-    single-host mode (the dev-box path).
+    With a coordinator configured, any failure is a genuine
+    misconfiguration — bad address, port in use, a peer missing — and
+    **propagates**; silently degrading a configured multi-host run to
+    single-host mode would train on 1/Nth of the data while looking
+    healthy.
     """
     global _initialized
     if _initialized:
@@ -63,41 +66,27 @@ def initialize_distributed(coordinator: str | None = None,
         _initialized = True
         return True
     coordinator = coordinator or os.environ.get("JAX_COORDINATOR_ADDRESS")
+    if not coordinator:
+        log.info("single-process mode (no coordinator configured)")
+        return False
     if num_processes is None:
         num_processes = _env_int("JAX_NUM_PROCESSES")
     if process_id is None:
         process_id = _env_int("JAX_PROCESS_ID")
+    if num_processes is None or process_id is None:
+        raise ValueError(
+            "coordinator address set but num_processes/process_id "
+            "missing (pass them or set JAX_NUM_PROCESSES / "
+            "JAX_PROCESS_ID)")
     kwargs = {}
     if initialization_timeout is not None:
         kwargs["initialization_timeout"] = initialization_timeout
-    if coordinator:
-        if num_processes is None or process_id is None:
-            raise ValueError(
-                "coordinator address set but num_processes/process_id "
-                "missing (pass them or set JAX_NUM_PROCESSES / "
-                "JAX_PROCESS_ID)")
-        jax.distributed.initialize(coordinator_address=coordinator,
-                                   num_processes=num_processes,
-                                   process_id=process_id, **kwargs)
-        _initialized = True
-        log.info("distributed init: process %d/%d, %d devices (%d local)",
-                 jax.process_index(), jax.process_count(),
-                 len(jax.devices()), len(jax.local_devices()))
-        return True
-    # No explicit configuration: try cluster auto-detection (GCP TPU
-    # metadata, SLURM, ...).  "coordinator_address should be defined" is
-    # jax's way of saying no cluster environment was found — the one
-    # case where single-host mode is the right answer.
-    try:
-        jax.distributed.initialize(**kwargs)
-    except ValueError as e:
-        if "coordinator_address" not in str(e):
-            raise
-        log.info("single-host mode (%s)", e)
-        return False
+    jax.distributed.initialize(coordinator_address=coordinator,
+                               num_processes=num_processes,
+                               process_id=process_id, **kwargs)
     _initialized = True
-    log.info("distributed init (auto-detected): process %d/%d, %d devices "
-             "(%d local)", jax.process_index(), jax.process_count(),
+    log.info("distributed init: process %d/%d, %d devices (%d local)",
+             jax.process_index(), jax.process_count(),
              len(jax.devices()), len(jax.local_devices()))
     return True
 
@@ -107,8 +96,8 @@ def add_distributed_cli_args(ap) -> None:
     g = ap.add_argument_group("distributed / liveness")
     g.add_argument("--coordinator", default=None,
                    help="host:port of the jax.distributed coordinator "
-                        "(or set JAX_COORDINATOR_ADDRESS); omit on GCP "
-                        "TPU VMs (metadata auto-detect) and single-host")
+                        "(or set JAX_COORDINATOR_ADDRESS); omit for a "
+                        "single-process run")
     g.add_argument("--num-processes", type=int, default=None)
     g.add_argument("--process-id", type=int, default=None)
     g.add_argument("--heartbeat-dir", default=None,

@@ -2,10 +2,16 @@
 
   PYTHONPATH=src python -m repro.launch.serve --arch chatglm3-6b --reduced \
       --requests 8 --max-new 16
+
+:func:`serve` is the same server as a callable: it takes the parsed
+arguments and, optionally, weights already placed on the host mesh, so
+one process can serve one set of weights under several ``--fusion``
+settings (``chip_smoke.py`` does).
 """
 from __future__ import annotations
 
 import argparse
+import functools
 import json
 import os
 import time
@@ -19,10 +25,12 @@ from repro.core.autotune import (add_granularity_cli_args,
 from repro.core.calibrate import (add_calibration_cli_args,
                                   warmup_and_calibrate)
 from repro.core.degrade import DegradationPolicy, set_degradation_policy
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.distributed import (add_distributed_cli_args,
                                       build_liveness_from_args,
                                       init_distributed_from_args)
-from repro.launch.mesh import make_context, make_host_mesh
+from repro.launch.mesh import (init_params_on_mesh, make_context,
+                               make_host_mesh)
 from repro.models.common import split_params
 from repro.parallel.sharding import FusionConfig
 from repro.runtime.chaos import (CollectiveTimeout, RankLost,
@@ -34,7 +42,14 @@ from repro.serve.engine import (DecodeEngine, PagedDecodeEngine, Request,
 from repro.serve.kv_cache import dense_cache_hbm_bytes, pool_hbm_bytes
 
 
-def main():
+def _bind(fn, params):
+    """``fn(params, *args)`` jitted with the weights as an argument: jit
+    bakes a closed-over array into the program as a constant, which at
+    chatglm3-6b width would be 12 GB of literals in the HLO."""
+    return functools.partial(jax.jit(fn), params)
+
+
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="chatglm3-6b")
     ap.add_argument("--reduced", action="store_true")
@@ -70,7 +85,15 @@ def main():
                          "— the cross-process drain-reshard-resume story")
     add_distributed_cli_args(ap)
     add_chaos_cli_args(ap)
-    args = ap.parse_args()
+    return ap
+
+
+def serve(args, params=None):
+    """Serve the requests ``args`` describe; returns (finished, engine).
+
+    ``params``: weight values already placed on the host mesh (as
+    :func:`~repro.launch.mesh.init_params_on_mesh` makes them for this
+    arch); ``None`` draws them from seed 0."""
     if args.auto_fuse:
         args.fusion = "auto"
 
@@ -87,8 +110,11 @@ def main():
         bundle = bundle.reduced()
     cfg = bundle.config
 
-    params_p = bundle.init_params(jax.random.PRNGKey(0))
-    params, param_specs = split_params(params_p)
+    if params is None:
+        params, param_specs = init_params_on_mesh(bundle, ctx)
+    else:
+        _, param_specs = split_params(
+            jax.eval_shape(bundle.init_params, jax.random.PRNGKey(0)))
     decode = bundle.decode_fn(ctx)
 
     if args.explain_comm:
@@ -100,12 +126,12 @@ def main():
         tok0 = np.zeros((args.batch, 1), np.int32)
         print(explain_comm(ectx, bundle.decode_fn(ectx), params, tok0,
                            bundle.init_cache(args.batch), 0))
-        return []
+        return [], None
 
     if args.fusion == "auto":
         from repro.analysis import auto_fuse
         decode = auto_fuse(ctx, decode)
-    decode_jit = jax.jit(lambda t, c, pos: decode(params, t, c, pos))
+    decode_jit = _bind(decode, params)
 
     if args.calibrate:
         warm_cache = bundle.init_cache(args.batch)
@@ -114,7 +140,7 @@ def main():
                              iters=args.calibrate_iters,
                              granularity=args.granularity)
         # measured decisions are read at trace time: re-jit for steady state
-        decode_jit = jax.jit(lambda t, c, pos: decode(params, t, c, pos))
+        decode_jit = _bind(decode, params)
 
     if args.degrade:
         set_degradation_policy(DegradationPolicy())
@@ -129,15 +155,14 @@ def main():
             # half the dense budget, rounded to a tp-divisible block count
             num_blocks = max(ctx.tp, (args.batch * cfg.max_seq // 2)
                              // args.block_size // ctx.tp * ctx.tp)
-        serve_fn = bundle.serve_step_fn(ctx)
-        serve_jit = jax.jit(
-            lambda t, pl, tb, pos, nn: serve_fn(params, t, pl, tb, pos, nn))
+        serve_jit = _bind(bundle.serve_step_fn(ctx), params)
         engine = PagedDecodeEngine(
             serve_jit, bundle.init_paged_pool, args.batch,
             num_blocks=num_blocks, block_size=args.block_size,
             max_seq=cfg.max_seq, chunk=args.chunk, n_stripes=ctx.tp)
         paged_b = pool_hbm_bytes(engine.pool)
-        dense_b = dense_cache_hbm_bytes(bundle.init_cache(args.batch))
+        dense_b = dense_cache_hbm_bytes(
+            jax.eval_shape(lambda: bundle.init_cache(args.batch)))
         print(f"paged pool: {num_blocks} x {args.block_size}-token blocks "
               f"= {paged_b / 2**20:.1f} MiB vs dense B x S_max "
               f"{dense_b / 2**20:.1f} MiB")
@@ -170,8 +195,7 @@ def main():
         params, _ = reshard_tree(params, param_specs, ctx)
         if args.paged:
             sfn = bundle.serve_step_fn(ctx)
-            new_jit = jax.jit(
-                lambda t, pl, tb, pos, nn: sfn(params, t, pl, tb, pos, nn))
+            new_jit = _bind(sfn, params)
             n = eng.reshard(new_jit, bundle.init_paged_pool, args.batch,
                             n_stripes=ctx.tp)
         else:
@@ -179,7 +203,7 @@ def main():
             if args.fusion == "auto":
                 from repro.analysis import auto_fuse
                 dec = auto_fuse(ctx, dec)
-            new_jit = jax.jit(lambda t, c, pos: dec(params, t, c, pos))
+            new_jit = _bind(dec, params)
             n = eng.reshard(new_jit, bundle.init_cache, args.batch)
         print(f"rank lost: mesh -> {dict(ctx.mesh.shape)}, "
               f"{n} in-flight requests re-queued")
@@ -231,6 +255,12 @@ def main():
         print(f"  req {r.uid}: prompt {r.prompt} -> {r.tokens[:12]}")
     if args.tune_cache:
         save_cache(args.tune_cache)
+    return finished, engine
+
+
+def main(argv=None):
+    enable_compile_cache()
+    finished, _ = serve(build_parser().parse_args(argv))
     return finished
 
 

@@ -27,11 +27,12 @@ from repro.core.calibrate import (add_calibration_cli_args,
                                   warmup_and_calibrate)
 from repro.core.degrade import DegradationPolicy, set_degradation_policy
 from repro.data.synthetic import DLRMBatches, LMBatches
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.distributed import (add_distributed_cli_args,
                                       build_liveness_from_args,
                                       init_distributed_from_args)
-from repro.launch.mesh import make_context, make_host_mesh
-from repro.models.common import split_params
+from repro.launch.mesh import (init_params_on_mesh, make_context,
+                               make_host_mesh)
 from repro.parallel.sharding import FusionConfig
 from repro.runtime.chaos import add_chaos_cli_args, build_fault_plan
 from repro.runtime.elastic import reshard_tree, shrink_context
@@ -110,6 +111,7 @@ def main():
     add_chaos_cli_args(ap)
     args = ap.parse_args()
     logging.basicConfig(level=logging.INFO)
+    enable_compile_cache()
     if args.auto_fuse:
         args.fusion = "auto"
 
@@ -125,8 +127,7 @@ def main():
     if args.reduced:
         bundle = bundle.reduced()
 
-    params_p = bundle.init_params(jax.random.PRNGKey(0))
-    params, param_specs = split_params(params_p)
+    params, param_specs = init_params_on_mesh(bundle, ctx)
     tc = TrainConfig(
         optimizer=OptimizerConfig(name=bundle.optimizer, lr=args.lr,
                                   warmup_steps=max(args.steps // 20, 5),

@@ -1,5 +1,8 @@
 import os
 
+# The suite runs on the CPU, also on a machine with a TPU: a test process
+# that took the chip would hold it from the program that needs it.
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
 # 8 local CPU devices for multi-device shard_map tests (NOT the 512-device
 # production mesh — that is exercised only by launch/dryrun.py).
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
