@@ -190,6 +190,7 @@ def fused_dispatch_a2a_pallas(xt, my_ep, ring_base, *, n_dev, axis_name,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((nd, b, e, c, d), xt.dtype),
+        name="fused_dispatch_a2a",
         compiler_params=pltpu.CompilerParams(collective_id=collective_id),
         interpret=interpret,
     )(ids, xt)

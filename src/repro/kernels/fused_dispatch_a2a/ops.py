@@ -51,10 +51,11 @@ def fused_dispatch_a2a_shard(xt, axis, *, comm_aware=True, chunks_per_rank=1,
         my_world = lax.axis_index(axis)
         my = lax.rem(my_world, n_dev)
         base = my_world - my
-        return fused_dispatch_a2a_pallas(
-            v, my, base, n_dev=n_dev, axis_name=axis, comm_aware=comm_aware,
-            chunks_per_rank=q, skew=skew,
-            wire=wire)
+        with jax.named_scope("fused_dispatch_a2a"):
+            return fused_dispatch_a2a_pallas(
+                v, my, base, n_dev=n_dev, axis_name=axis,
+                comm_aware=comm_aware, chunks_per_rank=q, skew=skew,
+                wire=wire)
 
     @jax.custom_vjp
     def a2a(v):

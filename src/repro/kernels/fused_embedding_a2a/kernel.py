@@ -148,6 +148,7 @@ def fused_embedding_a2a_pallas(tables, idx, my, *, n_dev, L, axis_name,
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
+        name="fused_embedding_a2a",
         out_shape=jax.ShapeDtypeStruct((b_loc, n_dev * t_loc, dp),
                                        tables.dtype),
         compiler_params=pltpu.CompilerParams(

@@ -29,9 +29,10 @@ def fused_embedding_a2a(ctx: ParallelContext, indices, tables, *,
     def local_fn(idx_l, tab_l):
         my = lax.axis_index(axis)
         n = lax.axis_size(axis)
-        return fused_embedding_a2a_pallas(
-            tab_l, idx_l, my, n_dev=n, L=L, axis_name=axis,
-            comm_aware=comm_aware)
+        with jax.named_scope("fused_embedding_a2a"):
+            return fused_embedding_a2a_pallas(
+                tab_l, idx_l, my, n_dev=n, L=L, axis_name=axis,
+                comm_aware=comm_aware)
 
     return shard_map(
         local_fn, mesh=ctx.mesh,

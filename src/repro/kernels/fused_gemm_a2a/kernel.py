@@ -282,6 +282,7 @@ def fused_gemm_a2a_pallas(xt, w_up, w_gate, w_down, my_ep, ring_base, *,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((nd, b, e, c, d), xt.dtype),
+        name="fused_gemm_a2a",
         compiler_params=pltpu.CompilerParams(collective_id=collective_id),
         interpret=interpret,
     )(ids, xt, w_up, w_gate, w_down)

@@ -66,10 +66,11 @@ def fused_gemm_a2a_shard(xt, w_up, w_gate, w_down, axis, *, act,
 
     def kernel_call(v, wu, wg, wd):
         n_dev, my, base = _ring_position(axis, ring_size)
-        return fused_gemm_a2a_pallas(
-            v, wu, wg, wd, my, base, n_dev=n_dev, axis_name=axis, act=act,
-            comm_aware=comm_aware, skew=skew,
-            tile_k=tile_k, tile_f=tile_f, wire=wire)
+        with jax.named_scope("fused_gemm_a2a"):
+            return fused_gemm_a2a_pallas(
+                v, wu, wg, wd, my, base, n_dev=n_dev, axis_name=axis,
+                act=act, comm_aware=comm_aware, skew=skew,
+                tile_k=tile_k, tile_f=tile_f, wire=wire)
 
     def ref_call(v, wu, wg, wd):
         y = expert_ffn_ref(v, wu, wg, wd, act)

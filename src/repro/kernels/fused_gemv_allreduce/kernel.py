@@ -315,6 +315,7 @@ def fused_matmul_allreduce_pallas(x, w, my_tp, *, n_dev, axis_name,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, n), x.dtype),
+        name="fused_gemv_allreduce",
         compiler_params=pltpu.CompilerParams(collective_id=collective_id),
         interpret=interpret,
     )(ids, x, w)

@@ -35,10 +35,11 @@ def fused_matmul_allreduce_shard(xl, wl, axis, *, comm_aware=True,
     n_dev = lax.axis_size(axis)
     my = lax.axis_index(axis)
     wire = "bf16" if wire == "fp8" else wire
-    return fused_matmul_allreduce_pallas(
-        xl, wl, my, n_dev=n_dev, axis_name=axis, comm_aware=comm_aware,
-        tile_n=tile_n, tile_k=tile_k,
-        vmem_budget_bytes=vmem_budget_bytes, wire=wire)
+    with jax.named_scope("fused_gemv_allreduce"):
+        return fused_matmul_allreduce_pallas(
+            xl, wl, my, n_dev=n_dev, axis_name=axis, comm_aware=comm_aware,
+            tile_n=tile_n, tile_k=tile_k,
+            vmem_budget_bytes=vmem_budget_bytes, wire=wire)
 
 
 def fused_matmul_allreduce(ctx: ParallelContext, x, w, *, comm_aware=True,

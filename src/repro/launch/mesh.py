@@ -72,6 +72,28 @@ def make_host_mesh(shape=None, axes=("data", "model"),
     return ParallelContext.from_mesh(mesh, fusion=fusion)
 
 
+def param_placements(bundle, ctx: ParallelContext):
+    """(weights, logical specs) without drawing a weight: each weight a
+    ``jax.ShapeDtypeStruct`` carrying its placement on ``ctx.mesh``, as
+    :func:`init_params_on_mesh` places it.  What an ahead-of-time lowering
+    of a step at the served shardings takes."""
+    struct, specs = split_params(jax.eval_shape(bundle.init_params,
+                                                jax.random.PRNGKey(0)))
+    is_spec = lambda x: isinstance(x, tuple) and all(
+        e is None or isinstance(e, str) for e in x)
+
+    def placement(logical, leaf):
+        # a dim the mesh axes do not divide stays whole (reduced configs)
+        axes = [ax if ax is None or leaf.shape[i] % math.prod(
+                    ctx.mesh.shape[a] for a in
+                    ((ax,) if isinstance(ax, str) else ax)) == 0 else None
+                for i, ax in enumerate(ctx.spec(*logical))]
+        return jax.ShapeDtypeStruct(leaf.shape, leaf.dtype,
+                                    sharding=NamedSharding(ctx.mesh, P(*axes)))
+
+    return jax.tree.map(placement, specs, struct, is_leaf=is_spec), specs
+
+
 def init_params_on_mesh(bundle, ctx: ParallelContext, seed: int = 0):
     """(params, logical specs), each weight drawn straight into its
     placement on ``ctx.mesh``.
@@ -84,20 +106,7 @@ def init_params_on_mesh(bundle, ctx: ParallelContext, seed: int = 0):
     ``bundle.init_params(PRNGKey(seed))`` to within one unit in the last
     place: XLA folds the constant factors of a normal draw, which can
     round its final bit differently from the op-by-op eager run."""
-    key = jax.random.PRNGKey(seed)
-    struct, specs = split_params(jax.eval_shape(bundle.init_params, key))
-    is_spec = lambda x: isinstance(x, tuple) and all(
-        e is None or isinstance(e, str) for e in x)
-
-    def placement(logical, leaf):
-        # a dim the mesh axes do not divide stays whole (reduced configs)
-        axes = [ax if ax is None or leaf.shape[i] % math.prod(
-                    ctx.mesh.shape[a] for a in
-                    ((ax,) if isinstance(ax, str) else ax)) == 0 else None
-                for i, ax in enumerate(ctx.spec(*logical))]
-        return NamedSharding(ctx.mesh, P(*axes))
-
-    shardings = jax.tree.map(placement, specs, struct, is_leaf=is_spec)
+    placed, specs = param_placements(bundle, ctx)
     init = jax.jit(lambda k: split_params(bundle.init_params(k))[0],
-                   out_shardings=shardings)
-    return init(key), specs
+                   out_shardings=jax.tree.map(lambda s: s.sharding, placed))
+    return init(jax.random.PRNGKey(seed)), specs

@@ -11,6 +11,7 @@ settings (``chip_smoke.py`` does).
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import functools
 import json
 import os
@@ -118,8 +119,6 @@ def serve(args, params=None):
     decode = bundle.decode_fn(ctx)
 
     if args.explain_comm:
-        import dataclasses
-
         from repro.analysis import explain_comm
         # analyze the bulk-traced decode graph, whatever --fusion says
         ectx = ctx.with_fusion(dataclasses.replace(fusion, mode="auto"))
@@ -253,6 +252,12 @@ def serve(args, params=None):
           f"batch={args.batch}, fusion={args.fusion})")
     for r in finished[:4]:
         print(f"  req {r.uid}: prompt {r.prompt} -> {r.tokens[:12]}")
+    if args.paged:
+        pool = engine.kv.stats()
+        counts = " ".join(f"{k} {v}" for k, v in
+                          dataclasses.asdict(engine.stats).items())
+        print(f"engine: {counts}; pool {pool.used_blocks} of "
+              f"{pool.num_blocks} blocks in use, peak {pool.peak_blocks}")
     if args.tune_cache:
         save_cache(args.tune_cache)
     return finished, engine

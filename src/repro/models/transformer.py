@@ -470,23 +470,30 @@ def _attn_serve(ctx, cfg: TransformerConfig, lp, x, layer_pool, tables,
     attention, so one causal pass covers both the cache and intra-chunk
     dependencies."""
     B, C, D = x.shape
-    h = rms_norm(x, lp["ln1"], cfg.norm_eps, plus_one=cfg.norm_plus_one)
     Hq, Hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
-    qkv = h @ lp["attn"]["w_qkv"]
-    q, k, v = jnp.split(qkv, [Hq * hd, (Hq + Hkv) * hd], axis=-1)
-    q = q.reshape(B, C, Hq, hd)
-    k = k.reshape(B, C, Hkv, hd)
-    v = v.reshape(B, C, Hkv, hd)
-    rpos = positions
-    if cfg.rope_style == "mrope":   # text-phase serving: three equal streams
-        rpos = jnp.broadcast_to(positions[None], (3, B, C))
-    q = _apply_rope_any(cfg, q, rpos)
-    k = _apply_rope_any(cfg, k, rpos)
-    kc = paged_cache_update(ctx, layer_pool["k"], k, tables, positions, valid)
-    vc = paged_cache_update(ctx, layer_pool["v"], v, tables, positions, valid)
-    o = paged_attention(ctx, q, kc, vc, tables, positions, window=window,
-                        scale=cfg.query_scale, softcap_val=cfg.attn_softcap)
-    out = o.reshape(B, C, Hq * hd) @ lp["attn"]["w_o"]
+    with jax.named_scope("attn.qkv"):
+        h = rms_norm(x, lp["ln1"], cfg.norm_eps, plus_one=cfg.norm_plus_one)
+        qkv = h @ lp["attn"]["w_qkv"]
+        q, k, v = jnp.split(qkv, [Hq * hd, (Hq + Hkv) * hd], axis=-1)
+        q = q.reshape(B, C, Hq, hd)
+        k = k.reshape(B, C, Hkv, hd)
+        v = v.reshape(B, C, Hkv, hd)
+        rpos = positions
+        if cfg.rope_style == "mrope":   # text-phase serving: three equal streams
+            rpos = jnp.broadcast_to(positions[None], (3, B, C))
+        q = _apply_rope_any(cfg, q, rpos)
+        k = _apply_rope_any(cfg, k, rpos)
+    with jax.named_scope("attn.kv_write"):
+        kc = paged_cache_update(ctx, layer_pool["k"], k, tables, positions,
+                                valid)
+        vc = paged_cache_update(ctx, layer_pool["v"], v, tables, positions,
+                                valid)
+    with jax.named_scope("attn.paged"):
+        o = paged_attention(ctx, q, kc, vc, tables, positions, window=window,
+                            scale=cfg.query_scale,
+                            softcap_val=cfg.attn_softcap)
+    with jax.named_scope("attn.out"):
+        out = o.reshape(B, C, Hq * hd) @ lp["attn"]["w_o"]
     return out, {"k": kc, "v": vc}
 
 
@@ -496,13 +503,15 @@ def _layer_serve(ctx, cfg, lp, x, layer_pool, tables, positions, valid, window):
     if cfg.post_norms:
         a = rms_norm(a, lp["post_ln1"], cfg.norm_eps, plus_one=cfg.norm_plus_one)
     x = x + a
-    h = rms_norm(x, lp["ln2"], cfg.norm_eps, plus_one=cfg.norm_plus_one)
-    if cfg.moe is not None and "router" in lp["ffn"]:
-        f = moe_mod.moe_apply(ctx, lp["ffn"], h, cfg.moe)
-    else:
-        f = mlp_apply(ctx, lp["ffn"], h, act=cfg.act, seq_sharded=False)
-    if cfg.post_norms:
-        f = rms_norm(f, lp["post_ln2"], cfg.norm_eps, plus_one=cfg.norm_plus_one)
+    with jax.named_scope("mlp"):
+        h = rms_norm(x, lp["ln2"], cfg.norm_eps, plus_one=cfg.norm_plus_one)
+        if cfg.moe is not None and "router" in lp["ffn"]:
+            f = moe_mod.moe_apply(ctx, lp["ffn"], h, cfg.moe)
+        else:
+            f = mlp_apply(ctx, lp["ffn"], h, act=cfg.act, seq_sharded=False)
+        if cfg.post_norms:
+            f = rms_norm(f, lp["post_ln2"], cfg.norm_eps,
+                         plus_one=cfg.norm_plus_one)
     return x + f, new_pool
 
 
@@ -522,8 +531,9 @@ def serve_step(ctx: ParallelContext, params, cfg: TransformerConfig,
     positions = pos[:, None] + jnp.arange(C, dtype=jnp.int32)[None, :]
     valid = jnp.arange(C)[None, :] < n_new[:, None]
     scale = cfg.d_model ** 0.5 if cfg.embed_scale else None
-    x = embedding_lookup(ctx, params["embed"], tokens, seq_shard=False,
-                         scale=scale).astype(cfg.cdtype)
+    with jax.named_scope("embed"):
+        x = embedding_lookup(ctx, params["embed"], tokens, seq_shard=False,
+                             scale=scale).astype(cfg.cdtype)
 
     new_prefix = []
     for i, lp in enumerate(params.get("prefix", [])):
@@ -552,10 +562,13 @@ def serve_step(ctx: ParallelContext, params, cfg: TransformerConfig,
     if new_prefix:
         new_pool["prefix"] = jax.tree.map(lambda *xs: jnp.stack(xs), *new_prefix)
 
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps, plus_one=cfg.norm_plus_one)
-    # each slot's logits come from its last *valid* token (prefill chunks
-    # only need the final position; idle slots produce garbage, discarded)
-    idx = jnp.clip(n_new - 1, 0, C - 1)
-    x_last = jnp.take_along_axis(x, idx[:, None, None], axis=1)   # [B,1,D]
-    logits = _lm_logits(ctx, params, cfg, x_last)
+    with jax.named_scope("lm_head"):
+        x = rms_norm(x, params["final_norm"], cfg.norm_eps,
+                     plus_one=cfg.norm_plus_one)
+        # each slot's logits come from its last *valid* token (prefill
+        # chunks only need the final position; idle slots produce garbage,
+        # discarded)
+        idx = jnp.clip(n_new - 1, 0, C - 1)
+        x_last = jnp.take_along_axis(x, idx[:, None, None], axis=1)  # [B,1,D]
+        logits = _lm_logits(ctx, params, cfg, x_last)
     return logits[:, 0], new_pool

@@ -29,6 +29,12 @@ Two backends:
     request retires; pool exhaustion preempts the newest-admitted
     request back to the queue instead of corrupting a neighbor.
 
+The paged engine marks its host work for the profiler: each ``step()`` is
+one ``serve.tick`` span holding ``serve.schedule``, ``serve.dispatch``,
+``serve.sample`` and ``serve.commit`` (``jax.profiler.TraceAnnotation``,
+which records only while a trace is running), and it counts what it did
+in :class:`EngineStats`.
+
 Elastic serving: :meth:`reshard` swaps the step function / cache (or
 block pool) for a different mesh mid-flight.  In-flight requests go back
 to the queue front with their generated tokens intact; on re-admission
@@ -48,6 +54,7 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.runtime.chaos import RankLost
 from repro.serve.kv_cache import OutOfBlocks, PagedKVCache
@@ -73,6 +80,20 @@ class Request:
     t_submit: float | None = None
     t_first: float | None = None
     t_done: float | None = None
+
+
+@dataclasses.dataclass
+class EngineStats:
+    """What :class:`PagedDecodeEngine` did, counted where each event
+    happens.  Pool occupancy is the block cache's own ``kv.stats()``."""
+    ticks: int = 0            # steps that ran the serve program
+    ticks_wide: int = 0       # of them at C = chunk
+    prefill_tokens: int = 0   # prompt (or replayed) tokens fed
+    decode_tokens: int = 0    # sampled tokens fed back, one per slot
+    admitted: int = 0
+    admit_deferred: int = 0   # admissions put back for want of blocks
+    preempted: int = 0
+    truncated: int = 0        # retired at the cache bound
 
 
 class DrainResult(list):
@@ -297,6 +318,7 @@ class PagedDecodeEngine(_EngineBase):
         # feed list per slot: prefix (or [bos] for empty prompts) still to
         # be pushed through the prefill path; consumed indexes into it.
         self._feed: list[list] = [[] for _ in range(batch_size)]
+        self.stats = EngineStats()
 
     # -- admission / preemption -------------------------------------------
     def _admit(self, finished: list):
@@ -314,7 +336,9 @@ class PagedDecodeEngine(_EngineBase):
                     # pool full: defer admission, keep FIFO order
                     self.kv.release(req.uid)
                     self.queue.appendleft(req)
+                    self.stats.admit_deferred += 1
                     return
+                self.stats.admitted += 1
                 self.slots[i] = req
                 self.pos[i] = 0
                 req.consumed = 0
@@ -332,6 +356,7 @@ class PagedDecodeEngine(_EngineBase):
         self.slots[i] = None
         self._feed[i] = []
         self.queue.appendleft(req)
+        self.stats.preempted += 1
 
     def _retire_at_bound(self, finished: list):
         for i, req in enumerate(self.slots):
@@ -340,12 +365,43 @@ class PagedDecodeEngine(_EngineBase):
                             "%d generated tokens — retiring truncated",
                             req.uid, self.max_seq, len(req.tokens))
                 req.truncated = True
+                self.stats.truncated += 1
                 self.kv.release(req.uid)
                 self._retire(i, req, finished)
 
     # -- the mixed prefill/decode step ------------------------------------
     def step(self):
         finished: list[Request] = []
+        st = self.stats
+        with TraceAnnotation("serve.tick") as span:
+            with TraceAnnotation("serve.schedule"):
+                tokens, n_new, remaining, tables, prefill, decode = (
+                    self._schedule(finished))
+            C = tokens.shape[1]
+            span.set_metadata(tick=st.ticks, width=C, decode=decode,
+                              prefill_tokens=prefill, queued=len(self.queue),
+                              blocks_used=self.kv.used_blocks)
+            if not n_new.any():
+                return np.zeros(self.batch, np.int32), finished
+            with TraceAnnotation("serve.dispatch"):
+                logits, self.pool = self.serve_fn(
+                    jnp.asarray(tokens), self.pool, jnp.asarray(tables),
+                    jnp.asarray(self.pos), jnp.asarray(n_new))
+            st.ticks += 1
+            st.ticks_wide += C == self.chunk
+            st.prefill_tokens += prefill
+            st.decode_tokens += decode
+            with TraceAnnotation("serve.sample"):
+                nxt = np.asarray(jnp.argmax(logits, axis=-1), np.int32)
+            with TraceAnnotation("serve.commit"):
+                self._commit(nxt, n_new, remaining, finished)
+        return nxt, finished
+
+    def _schedule(self, finished: list):
+        """Retire at the bound, admit, choose the chunk width, grow each
+        working slot's blocks (preempting where the pool is out) and
+        build the step's inputs.  Returns (tokens [B, C], n_new, remaining
+        feed per slot, tables, prefill tokens, decoding slots)."""
         self._retire_at_bound(finished)
         self._admit(finished)
         # chunk width: the wide graph only when some slot is mid-prefill
@@ -355,6 +411,7 @@ class PagedDecodeEngine(_EngineBase):
 
         tokens = np.zeros((self.batch, C), np.int32)
         n_new = np.zeros(self.batch, np.int32)
+        prefill = decode = 0
         for i, req in enumerate(self.slots):
             if req is None:
                 continue
@@ -371,18 +428,18 @@ class PagedDecodeEngine(_EngineBase):
                 self._preempt(i, req)
                 continue
             n_new[i] = n
+            if rem > 0:
+                prefill += n
+            else:
+                decode += 1
         tables = self.kv.tables_for(
             [r.uid if r is not None and n_new[i] > 0 else None
              for i, r in enumerate(self.slots)])
+        return tokens, n_new, remaining, tables, prefill, decode
 
-        if not n_new.any():
-            return np.zeros(self.batch, np.int32), finished
-
-        logits, self.pool = self.serve_fn(
-            jnp.asarray(tokens), self.pool, jnp.asarray(tables),
-            jnp.asarray(self.pos), jnp.asarray(n_new))
-        nxt = np.asarray(jnp.argmax(logits, axis=-1), np.int32)
-
+    def _commit(self, nxt, n_new, remaining, finished: list):
+        """Advance each slot that worked this tick: its position, its
+        prefill progress or its sampled token, and retirement."""
         for i, req in enumerate(self.slots):
             if req is None or n_new[i] == 0:
                 continue
@@ -403,7 +460,6 @@ class PagedDecodeEngine(_EngineBase):
             if tok == self.eos or len(req.tokens) >= req.max_new:
                 self.kv.release(req.uid)
                 self._retire(i, req, finished)
-        return nxt, finished
 
     # -- elasticity --------------------------------------------------------
     def reshard(self, serve_fn: Callable, init_pool_fn: Callable,

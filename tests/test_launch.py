@@ -6,7 +6,8 @@ import pytest
 
 from repro.configs.registry import get_arch
 from repro.launch import compile_cache, distributed
-from repro.launch.mesh import init_params_on_mesh, make_host_mesh
+from repro.launch.mesh import (init_params_on_mesh, make_host_mesh,
+                               param_placements)
 from repro.models.common import split_params
 
 
@@ -15,7 +16,8 @@ def test_init_params_on_mesh_places_the_eager_values(arch):
     """Drawn under one jit straight into the mesh placement, the weights
     are the eager init's to the last bit, and each leaf is sharded as its
     logical spec says wherever the mesh divides the dim (zamba2's reduced
-    widths do not always)."""
+    widths do not always), as ``param_placements`` describes without
+    drawing them."""
     bundle = get_arch(arch).reduced()
     ctx = make_host_mesh()
     eager, specs = split_params(bundle.init_params(jax.random.PRNGKey(0)))
@@ -24,6 +26,11 @@ def test_init_params_on_mesh_places_the_eager_values(arch):
     for a, b in zip(jax.tree.leaves(eager), jax.tree.leaves(placed)):
         np.testing.assert_array_max_ulp(np.asarray(a), np.asarray(b),
                                         maxulp=1)
+    described, described_specs = param_placements(bundle, ctx)
+    assert described_specs == specs
+    for s, b in zip(jax.tree.leaves(described), jax.tree.leaves(placed)):
+        assert (s.shape, s.dtype, s.sharding) == (b.shape, b.dtype,
+                                                  b.sharding)
     table = placed["embed"]["table"] if "embed" in placed else None
     if table is not None:
         assert table.sharding.spec == ctx.spec("tp", "fsdp")

@@ -11,6 +11,7 @@ process may load the TPU library, and every test worker imports every
 test file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -46,9 +47,13 @@ def _struct(mesh, shape, dtype, spec):
                                 sharding=NamedSharding(mesh, spec))
 
 
-def _assert_kernel_compiles(fn, *args):
+def _assert_kernel_compiles(fn, *args, name):
+    """Compiles, holds the Pallas call, and names its instruction after
+    the kernel's family (the name a trace shows for its op)."""
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text
+    assert re.search(rf"%{name}\.\d+ = ", text)
+    return text
 
 
 def _ring_pos():
@@ -73,7 +78,8 @@ def test_gemv_allreduce_kernel_compiles(mesh, k, n, rows):
 
     _assert_kernel_compiles(
         fn, _struct(mesh, (rows, k), jnp.bfloat16, P(None, "model")),
-        _struct(mesh, (k, n), jnp.bfloat16, P("model", None)))
+        _struct(mesh, (k, n), jnp.bfloat16, P("model", None)),
+        name="fused_gemv_allreduce")
 
 
 def test_dispatch_a2a_kernel_compiles(mesh):
@@ -92,7 +98,8 @@ def test_dispatch_a2a_kernel_compiles(mesh):
 
     _assert_kernel_compiles(
         fn, _struct(mesh, (N_DEV, N_DEV, b, e_loc, cap, d), jnp.bfloat16,
-                    P("model")))
+                    P("model")),
+        name="fused_dispatch_a2a")
 
 
 def test_gemm_a2a_kernel_compiles(mesh):
@@ -115,7 +122,8 @@ def test_gemm_a2a_kernel_compiles(mesh):
     w = lambda *shape: _struct(mesh, shape, jnp.bfloat16, P("model"))
     _assert_kernel_compiles(
         fn, w(N_DEV, N_DEV, b, e_loc, cap, d), w(N_DEV * e_loc, d, f),
-        w(N_DEV * e_loc, d, f), w(N_DEV * e_loc, f, d))
+        w(N_DEV * e_loc, d, f), w(N_DEV * e_loc, f, d),
+        name="fused_gemm_a2a")
 
 
 def test_embedding_a2a_kernel_compiles(mesh):
@@ -139,7 +147,8 @@ def test_embedding_a2a_kernel_compiles(mesh):
         fn, _struct(mesh, (batch, N_DEV * t_loc, pooling), jnp.int32,
                     P(None, "model", None)),
         _struct(mesh, (N_DEV * t_loc, vocab, d), jnp.float32,
-                P("model", None, None)))
+                P("model", None, None)),
+        name="fused_embedding_a2a")
 
 
 def test_chatglm3_kernel_decode_step_compiles(mesh, monkeypatch):
@@ -169,6 +178,12 @@ def test_chatglm3_kernel_decode_step_compiles(mesh, monkeypatch):
     cfg = bundle.config
     pool = jax.eval_shape(lambda: bundle.init_paged_pool(512, 16))
     i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
-    _assert_kernel_compiles(
+    text = _assert_kernel_compiles(
         bundle.serve_step_fn(ctx), params, i32(4, 1), pool,
-        i32(4, -(-cfg.max_seq // 16)), i32(4), i32(4))
+        i32(4, -(-cfg.max_seq // 16)), i32(4), i32(4),
+        name="fused_gemv_allreduce")
+    # the served step's named scopes reach the chip compiler's metadata:
+    # the kernel under the MLP, the gather under paged attention
+    assert re.search(r'op_name="[^"]*/mlp/[^"]*/fused_gemv_allreduce/'
+                     r'[^"]*pallas_call"', text)
+    assert re.search(r'gather\([^\n]*op_name="[^"]*/attn\.paged/', text)
