@@ -256,8 +256,11 @@ def serve(args, params=None):
         pool = engine.kv.stats()
         counts = " ".join(f"{k} {v}" for k, v in
                           dataclasses.asdict(engine.stats).items())
-        print(f"engine: {counts}; pool {pool.used_blocks} of "
-              f"{pool.num_blocks} blocks in use, peak {pool.peak_blocks}")
+        st = engine.stats
+        read = st.kv_blocks_read / max(st.kv_blocks_table, 1)
+        print(f"engine: {counts}; table blocks read {read:.1%}; pool "
+              f"{pool.used_blocks} of {pool.num_blocks} blocks in use, peak "
+              f"{pool.peak_blocks}")
     if args.tune_cache:
         save_cache(args.tune_cache)
     return finished, engine

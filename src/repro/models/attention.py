@@ -28,6 +28,8 @@ from repro.core.collectives import (attention_partial_merge, ring_permute,
                                     split_ring_payload, wire_cast,
                                     wire_uncast)
 from repro.core.scheduling import sub_chunk_service_order
+from repro.kernels.paged_attention import (paged_attention_kernel_supported,
+                                          paged_attention_shard)
 from repro.parallel.sharding import ParallelContext
 from repro.compat import shard_map
 
@@ -522,12 +524,15 @@ def cache_update(ctx: ParallelContext, cache, new, pos):
 # ---------------------------------------------------------------------------
 # The dense decode cache above is [B, S_max, ...] — every slot pays for
 # the longest request it might ever serve.  The paged layout instead
-# shares one pool of fixed-size blocks ([NB, block, ...], blocks sharded
-# over tp) among all in-flight requests; a per-request *block table*
-# [B, MB] maps the request's sequence-block m to the pool block that
-# holds it (allocation/free lives host-side in
+# shares one pool of fixed-size blocks ([L, NB, block, Hkv*hd]: every
+# layer's blocks stacked, heads flattened into the lane dimension, blocks
+# sharded over tp) among all in-flight requests; a per-request *block
+# table* [B, MB] maps the request's sequence-block m to the pool block
+# that holds it (allocation/free lives host-side in
 # :class:`repro.serve.kv_cache.PagedKVCache`).  Ragged sequences then
-# cost HBM proportional to their actual lengths, not B x S_max.
+# cost HBM proportional to their actual lengths, not B x S_max.  The
+# step writes and reads each layer's blocks in the stacked pool itself,
+# by layer index, so no layer's pool is sliced out and written back.
 #
 # Sharding: pool blocks are sharded *contiguously* over the tp axis
 # (rank d owns global blocks [d*NB/n, (d+1)*NB/n)); each rank writes and
@@ -535,47 +540,51 @@ def cache_update(ctx: ParallelContext, cache, new, pos):
 # same pmax/psum pair as the dense decode path.  The allocator stripes
 # handouts across ranks so load stays balanced.
 
-def paged_cache_update(ctx: ParallelContext, pool, new, tables, pos, valid):
-    """Scatter a token chunk into the block pool.
+def paged_cache_update(ctx: ParallelContext, pool, new, tables, pos, valid,
+                       layer):
+    """Scatter a token chunk into layer ``layer`` of the block pool.
 
-    pool: [NB, block, *rest] (blocks sharded over tp); new: [B, C, *rest];
-    tables: [B, MB] global block ids; pos: [B, C] global positions;
-    valid: [B, C] bool (False rows — padding past a slot's ``n_new``, or
-    idle slots — are dropped).  Writes land only on the rank owning the
-    target block; positions whose block index falls outside the table are
-    dropped, never clamped."""
+    pool: [L, NB, block, *rest] (blocks sharded over tp); new: [B, C,
+    *rest]; tables: [B, MB] global block ids; pos: [B, C] global
+    positions; valid: [B, C] bool (False rows — padding past a slot's
+    ``n_new``, or idle slots — are dropped).  Writes land only on the
+    rank owning the target block; positions whose block index falls
+    outside the table are dropped, never clamped."""
     axis, n = ctx.tp_axis, ctx.tp
-    NB, block = pool.shape[:2]
-    rest = (None,) * (pool.ndim - 2)
+    NB, block = pool.shape[1:3]
+    rest = (None,) * (pool.ndim - 3)
     B, C = pos.shape
     MB = tables.shape[1]
     nb_loc = NB // n
 
-    def local_fn(pl, nl, tbl, p, ok):
+    def local_fn(pl, nl, tbl, p, ok, li):
         d = lax.axis_index(axis)
         blk = p // block                                   # [B, C] seq-block
         ok = ok & (blk < MB)
         g = jnp.take_along_axis(tbl, jnp.clip(blk, 0, MB - 1), axis=1)
         local = g - d * nb_loc
         rows = jnp.where(ok & (local >= 0) & (local < nb_loc), local, nb_loc)
-        return pl.at[rows.reshape(-1), (p % block).reshape(-1)].set(
+        return pl.at[li, rows.reshape(-1), (p % block).reshape(-1)].set(
             nl.reshape((B * C,) + nl.shape[2:]).astype(pl.dtype), mode="drop")
 
     return shard_map(
         local_fn, mesh=ctx.mesh,
-        in_specs=(P(axis, None, *rest), P(None, None, *rest), P(), P(), P()),
-        out_specs=P(axis, None, *rest),
+        in_specs=(P(None, axis, None, *rest), P(None, None, *rest), P(), P(),
+                  P(), P()),
+        out_specs=P(None, axis, None, *rest),
         check_vma=False,
-    )(pool, new, tables, pos, valid)
+    )(pool, new, tables, pos, valid, jnp.asarray(layer, jnp.int32))
 
 
 def paged_attention(
     ctx: ParallelContext,
     q,                  # [B, C, Hq, hd] replicated over tp
-    pool_k, pool_v,     # [NB, block, Hkv, hd] blocks sharded over tp
+    pool_k, pool_v,     # [L, NB, block, Hkv*hd] blocks sharded over tp
     tables,             # [B, MB] int32 global block ids
     pos,                # [B, C] global position of each query token
     *,
+    layer,              # int32 scalar: the layer of the pools to read
+    n_new,              # [B] new tokens per slot (0 = idle)
     window: int | None = None,
     scale: float | None = None,
     softcap_val: float | None = None,
@@ -583,27 +592,34 @@ def paged_attention(
 ):
     """Flash attention of a token chunk against a paged KV pool.
 
-    Each rank gathers the table blocks it owns, runs the shared
-    flash-update machinery over them span by span (per-slot causal /
-    window masks — the chunk's own KV is already in the pool, so one
-    pass covers both the cache and intra-chunk causality), and the
-    partials merge with the same pmax/psum pair as the dense decode
-    path.  C=1 is the pure-decode fast path; C>1 is a prefill chunk
-    (continuous batching mixes both in one call via the per-slot
-    positions)."""
+    On a TPU, for lane-aligned heads and sublane-aligned blocks, each
+    rank runs the ragged paged kernel (:mod:`repro.kernels.paged_attention`):
+    it reads, straight from the stacked pool, only the blocks it owns
+    that the slot's queries can see.  Elsewhere each rank gathers the
+    table blocks it owns of the layer and runs the shared flash-update
+    machinery over them span by span.  Either way the masks are per slot
+    (causal by global position, window), the chunk's own KV is already in
+    the pool, so one pass covers both the cache and intra-chunk
+    causality, and the partials merge with the same pmax/psum pair as
+    the dense decode path.  C=1 is the pure-decode fast path; C>1 is a
+    prefill chunk (continuous batching mixes both in one call via the
+    per-slot positions)."""
     axis, n = ctx.tp_axis, ctx.tp
-    NB, block, Hkv, hd = pool_k.shape
-    B, C, Hq, _ = q.shape
+    _, NB, block, width = pool_k.shape
+    B, C, Hq, hd = q.shape
+    Hkv = width // hd
     g = Hq // Hkv
     scale = scale if scale is not None else hd ** -0.5
     MB = tables.shape[1]
     nb_loc = NB // n
     span = max(1, min(MB, kv_block // block))   # table blocks per flash span
 
-    def local_fn(ql, pkl, pvl, tbl, p):
+    def gather_fn(ql, pkl, pvl, tbl, p, _, li):
         d = lax.axis_index(axis)
         b = ql.shape[0]
         q5 = ql.reshape(b, C, Hkv, g, hd)
+        pkl = pkl[li].reshape(nb_loc, block, Hkv, hd)
+        pvl = pvl[li].reshape(nb_loc, block, Hkv, hd)
         local = tbl - d * nb_loc                           # [B, MB]
         own = (local >= 0) & (local < nb_loc)
         rows = jnp.where(own, local, 0)
@@ -621,14 +637,28 @@ def paged_attention(
             if window is not None:
                 mask &= p[:, :, None] - kpos[None, None, :] < window
             carry = _flash_update(carry, q5, ks, vs, mask, scale, softcap_val)
-        m, l, o = carry
+        return carry
+
+    def kernel_fn(ql, pkl, pvl, tbl, p, nn, li):
+        return paged_attention_shard(
+            ql, pkl, pvl, li, tbl, p[:, 0], nn, lax.axis_index(axis) * nb_loc,
+            window=window, scale=scale, softcap=softcap_val)
+
+    partials = (kernel_fn if paged_attention_kernel_supported(block, hd)
+                else gather_fn)
+
+    def local_fn(ql, pkl, pvl, tbl, p, nn, li):
+        b = ql.shape[0]
+        m, l, o = partials(ql, pkl, pvl, tbl, p, nn, li)
         o = attention_partial_merge(o, m, l, axis)         # [b,hk,g,C,d]
         return o.transpose(0, 3, 1, 2, 4).reshape(b, C, Hq, hd)
 
+    pool_spec = P(None, axis, None, None)
     return shard_map(
         local_fn, mesh=ctx.mesh,
-        in_specs=(P(None, None, None, None), P(axis, None, None, None),
-                  P(axis, None, None, None), P(), P()),
+        in_specs=(P(None, None, None, None), pool_spec, pool_spec, P(), P(),
+                  P(), P()),
         out_specs=P(None, None, None, None),
         check_vma=False,
-    )(q, pool_k, pool_v, tables, pos).astype(q.dtype)
+    )(q, pool_k, pool_v, tables, pos, jnp.asarray(n_new, jnp.int32),
+      jnp.asarray(layer, jnp.int32)).astype(q.dtype)

@@ -434,8 +434,9 @@ def _lm_logits(ctx, params, cfg, x):
 def init_paged_pool(cfg: TransformerConfig, num_blocks: int, block_size: int):
     """Zeroed paged KV block pools shared by all in-flight requests.
 
-    Layout: {"scan": {"k": [L, NB, block, Hkv, hd], "v": ...}} (+ "prefix"
-    for dense-prefix layers); blocks are sharded over tp, mapped to
+    Layout: {"scan": {"k": [L, NB, block, Hkv * hd], "v": ...}} (+
+    "prefix" for dense-prefix layers), heads flattened into the lane
+    dimension; blocks are sharded over tp, mapped to
     requests via host-side block tables (repro.serve.kv_cache).  GQA only
     — MLA keeps the dense latent cache for now (registry gates on
     ``supports_paged``)."""
@@ -444,7 +445,7 @@ def init_paged_pool(cfg: TransformerConfig, num_blocks: int, block_size: int):
             f"paged KV requires attn_type='gqa' ({cfg.name} is {cfg.attn_type})")
 
     def one(n):
-        shape = (n, num_blocks, block_size, cfg.n_kv_heads, cfg.hd)
+        shape = (n, num_blocks, block_size, cfg.n_kv_heads * cfg.hd)
         return {"k": jnp.zeros(shape, cfg.cdtype),
                 "v": jnp.zeros(shape, cfg.cdtype)}
 
@@ -461,14 +462,15 @@ def pool_logical_specs(cfg: TransformerConfig, pool):
     return jax.tree.map(spec, pool)
 
 
-def _attn_serve(ctx, cfg: TransformerConfig, lp, x, layer_pool, tables,
+def _attn_serve(ctx, cfg: TransformerConfig, lp, x, pool, layer, tables,
                 positions, valid, window):
-    """Chunked attention against the paged pool.  x: [B, C, D]; positions
-    [B, C] are per-slot global offsets (decode: C=1 at pos; prefill: a
-    C-token chunk starting at pos); ``valid`` masks padding/idle rows out
-    of the cache write.  The chunk's own KV lands in the pool *before*
-    attention, so one causal pass covers both the cache and intra-chunk
-    dependencies."""
+    """Chunked attention against layer ``layer`` of the stacked paged
+    pool.  x: [B, C, D]; positions [B, C] are per-slot global offsets
+    (decode: C=1 at pos; prefill: a C-token chunk starting at pos);
+    ``valid`` masks padding/idle rows out of the cache write.  The
+    chunk's own KV lands in the pool *before* attention, so one causal
+    pass covers both the cache and intra-chunk dependencies.  Returns
+    the attention output and the updated pool."""
     B, C, D = x.shape
     Hq, Hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
     with jax.named_scope("attn.qkv"):
@@ -484,22 +486,24 @@ def _attn_serve(ctx, cfg: TransformerConfig, lp, x, layer_pool, tables,
         q = _apply_rope_any(cfg, q, rpos)
         k = _apply_rope_any(cfg, k, rpos)
     with jax.named_scope("attn.kv_write"):
-        kc = paged_cache_update(ctx, layer_pool["k"], k, tables, positions,
-                                valid)
-        vc = paged_cache_update(ctx, layer_pool["v"], v, tables, positions,
-                                valid)
+        kc = paged_cache_update(ctx, pool["k"], k.reshape(B, C, Hkv * hd),
+                                tables, positions, valid, layer)
+        vc = paged_cache_update(ctx, pool["v"], v.reshape(B, C, Hkv * hd),
+                                tables, positions, valid, layer)
     with jax.named_scope("attn.paged"):
-        o = paged_attention(ctx, q, kc, vc, tables, positions, window=window,
-                            scale=cfg.query_scale,
+        o = paged_attention(ctx, q, kc, vc, tables, positions, layer=layer,
+                            n_new=valid.sum(axis=1, dtype=jnp.int32),
+                            window=window, scale=cfg.query_scale,
                             softcap_val=cfg.attn_softcap)
     with jax.named_scope("attn.out"):
         out = o.reshape(B, C, Hq * hd) @ lp["attn"]["w_o"]
     return out, {"k": kc, "v": vc}
 
 
-def _layer_serve(ctx, cfg, lp, x, layer_pool, tables, positions, valid, window):
-    a, new_pool = _attn_serve(ctx, cfg, lp, x, layer_pool, tables, positions,
-                              valid, window)
+def _layer_serve(ctx, cfg, lp, x, pool, layer, tables, positions, valid,
+                 window):
+    a, new_pool = _attn_serve(ctx, cfg, lp, x, pool, layer, tables,
+                              positions, valid, window)
     if cfg.post_norms:
         a = rms_norm(a, lp["post_ln1"], cfg.norm_eps, plus_one=cfg.norm_plus_one)
     x = x + a
@@ -535,32 +539,22 @@ def serve_step(ctx: ParallelContext, params, cfg: TransformerConfig,
         x = embedding_lookup(ctx, params["embed"], tokens, seq_shard=False,
                              scale=scale).astype(cfg.cdtype)
 
-    new_prefix = []
+    new_pool = dict(pool)
     for i, lp in enumerate(params.get("prefix", [])):
-        lc = jax.tree.map(lambda c: c[i], pool["prefix"])
-        x, nc = _layer_serve(ctx, cfg, lp["l0"], x, lc, tables, positions,
-                             valid, cfg.layer_window(0))
-        new_prefix.append(nc)
+        x, new_pool["prefix"] = _layer_serve(
+            ctx, cfg, lp["l0"], x, new_pool["prefix"], i, tables, positions,
+            valid, cfg.layer_window(0))
 
     def group_body(carry, group_params):
         h, scan_pool, li = carry
         for i in range(cfg.pattern_len):
-            lc = jax.tree.map(
-                lambda c: lax.dynamic_index_in_dim(c, li + i, 0, keepdims=False),
-                scan_pool)
-            h, nc = _layer_serve(ctx, cfg, group_params[f"l{i}"], h, lc,
-                                 tables, positions, valid, cfg.layer_window(i))
-            scan_pool = jax.tree.map(
-                lambda c, n: lax.dynamic_update_slice_in_dim(c, n[None], li + i,
-                                                             axis=0),
-                scan_pool, nc)
+            h, scan_pool = _layer_serve(ctx, cfg, group_params[f"l{i}"], h,
+                                        scan_pool, li + i, tables, positions,
+                                        valid, cfg.layer_window(i))
         return (h, scan_pool, li + cfg.pattern_len), ()
 
-    (x, new_scan, _), _ = lax.scan(group_body, (x, pool["scan"], jnp.int32(0)),
-                                   params["layers"])
-    new_pool = {"scan": new_scan}
-    if new_prefix:
-        new_pool["prefix"] = jax.tree.map(lambda *xs: jnp.stack(xs), *new_prefix)
+    (x, new_pool["scan"], _), _ = lax.scan(
+        group_body, (x, pool["scan"], jnp.int32(0)), params["layers"])
 
     with jax.named_scope("lm_head"):
         x = rms_norm(x, params["final_norm"], cfg.norm_eps,

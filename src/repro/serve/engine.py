@@ -57,7 +57,7 @@ import numpy as np
 from jax.profiler import TraceAnnotation
 
 from repro.runtime.chaos import RankLost
-from repro.serve.kv_cache import OutOfBlocks, PagedKVCache
+from repro.serve.kv_cache import FREE_BLOCK, OutOfBlocks, PagedKVCache
 
 log = logging.getLogger("repro.serve")
 
@@ -94,6 +94,8 @@ class EngineStats:
     admit_deferred: int = 0   # admissions put back for want of blocks
     preempted: int = 0
     truncated: int = 0        # retired at the cache bound
+    kv_blocks_read: int = 0   # live table blocks of the working slots
+    kv_blocks_table: int = 0  # working slots x table width
 
 
 class DrainResult(list):
@@ -378,9 +380,11 @@ class PagedDecodeEngine(_EngineBase):
                 tokens, n_new, remaining, tables, prefill, decode = (
                     self._schedule(finished))
             C = tokens.shape[1]
+            blocks_read = int((tables != FREE_BLOCK).sum())
             span.set_metadata(tick=st.ticks, width=C, decode=decode,
                               prefill_tokens=prefill, queued=len(self.queue),
-                              blocks_used=self.kv.used_blocks)
+                              blocks_used=self.kv.used_blocks,
+                              blocks_read=blocks_read)
             if not n_new.any():
                 return np.zeros(self.batch, np.int32), finished
             with TraceAnnotation("serve.dispatch"):
@@ -391,6 +395,8 @@ class PagedDecodeEngine(_EngineBase):
             st.ticks_wide += C == self.chunk
             st.prefill_tokens += prefill
             st.decode_tokens += decode
+            st.kv_blocks_read += blocks_read
+            st.kv_blocks_table += int((n_new > 0).sum()) * tables.shape[1]
             with TraceAnnotation("serve.sample"):
                 nxt = np.asarray(jnp.argmax(logits, axis=-1), np.int32)
             with TraceAnnotation("serve.commit"):

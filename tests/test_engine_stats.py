@@ -3,6 +3,7 @@ sit beside (kv.stats()), on a fake serve program and a pool of a few
 blocks."""
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.serve.engine import PagedDecodeEngine, Request
 
@@ -86,3 +87,36 @@ def test_reshard_keeps_the_counters():
     assert eng.stats is before and before.ticks == 1
     assert eng.run_until_drained(max_steps=20).drained
     assert eng.stats.admitted == 2
+
+
+def test_blocks_read_counts_the_live_table_blocks():
+    """kv_blocks_read sums, over the ticks, the table entries the step was
+    given that name a block; kv_blocks_table the working slots' whole
+    tables.  Idle slots count in neither."""
+    from repro.serve.kv_cache import FREE_BLOCK
+
+    seen = []
+
+    class Recording(FakeServe):
+        def __call__(self, tokens, pool, tables, pos, n_new):
+            t, n = map(np.asarray, (tables, n_new))
+            seen.append(((t != FREE_BLOCK).sum(), (n > 0).sum() * t.shape[1],
+                         sum(len(self.engine.kv.blocks_for(uid))
+                             for uid in self.engine.kv._tables)))
+            return super().__call__(tokens, pool, tables, pos, n_new)
+
+    fn = Recording()
+    eng = PagedDecodeEngine(fn, lambda nb, bs: None, 3, num_blocks=16,
+                            block_size=2, max_seq=16, chunk=4)
+    fn.engine = eng
+    for uid, (n, m) in enumerate([(5, 3), (1, 6), (3, 2), (2, 2)]):
+        eng.submit(Request(uid=uid, prompt=list(range(1, n + 1)),
+                           max_new=m))
+    assert eng.run_until_drained(max_steps=50).drained
+    st = eng.stats
+    assert st.ticks == len(seen) > 3
+    assert st.kv_blocks_read == sum(r for r, _, _ in seen)
+    assert st.kv_blocks_table == sum(t for _, t, _ in seen)
+    # every held block is in some working slot's table
+    assert all(r == held for r, _, held in seen)
+    assert 0 < st.kv_blocks_read < st.kv_blocks_table

@@ -151,12 +151,45 @@ def test_embedding_a2a_kernel_compiles(mesh):
         name="fused_embedding_a2a")
 
 
+# the benchmark's cells: chatglm3-6b on one chip (32 slots, 2,048 pool
+# blocks) and phi3-medium-14b at tp=4 (16 slots, 1,024 blocks, window
+# 2047), 16-token blocks, 256-block tables, the pool of every layer
+@pytest.mark.parametrize("cell", ["glm6b", "phi3m_tp4"])
+@pytest.mark.parametrize("chunk", [1, 8], ids=["decode", "prefill_chunk"])
+def test_paged_attention_kernel_compiles(topo, mesh, monkeypatch, cell,
+                                         chunk):
+    import repro.kernels
+    from repro.kernels.paged_attention import ops as paged_ops
+    from repro.models.attention import paged_attention
+    from repro.parallel.sharding import ParallelContext
+
+    monkeypatch.setattr(repro.kernels, "interpret_mode", lambda: False)
+    monkeypatch.setattr(paged_ops, "interpret_mode", lambda: False)
+    layers, b, hq, hkv, nb, window, tp = {
+        "glm6b": (28, 32, 32, 2, 2048, None, 1),
+        "phi3m_tp4": (40, 16, 40, 10, 1024, 2047, N_DEV)}[cell]
+    on = Mesh(np.array(topo.devices[:tp]).reshape(1, tp), ("data", "model"))
+    ctx = ParallelContext.from_mesh(on)
+    rep = lambda *shape: _struct(on, shape, jnp.int32, P())
+    pool = _struct(on, (layers, nb, 16, hkv * 128), jnp.bfloat16,
+                   P(None, "model"))
+
+    def fn(q, k, v, tables, positions, n_new, layer):
+        return paged_attention(ctx, q, k, v, tables, positions, layer=layer,
+                               n_new=n_new, window=window)
+
+    _assert_kernel_compiles(
+        fn, _struct(on, (b, chunk, hq, 128), jnp.bfloat16, P()), pool, pool,
+        rep(b, 256), rep(b, chunk), rep(b), rep(), name="paged_attention")
+
+
 def test_chatglm3_kernel_decode_step_compiles(mesh, monkeypatch):
     """The whole chatglm3-6b paged decode step at full width, tp=4,
     ``--fusion kernel``: the kernel is chosen (not the XLA fallback)."""
     import repro.kernels
     from repro.configs.registry import get_arch
     from repro.kernels.fused_gemv_allreduce import ops as gemv_ops
+    from repro.kernels.paged_attention import ops as paged_ops
     from repro.models.common import split_params
     from repro.parallel.sharding import FusionConfig, ParallelContext
 
@@ -164,6 +197,7 @@ def test_chatglm3_kernel_decode_step_compiles(mesh, monkeypatch):
     # interpreter; this program is for the described chip
     monkeypatch.setattr(repro.kernels, "interpret_mode", lambda: False)
     monkeypatch.setattr(gemv_ops, "interpret_mode", lambda: False)
+    monkeypatch.setattr(paged_ops, "interpret_mode", lambda: False)
 
     bundle = get_arch("chatglm3-6b")
     ctx = ParallelContext.from_mesh(mesh, fusion=FusionConfig(mode="kernel"))
@@ -183,7 +217,9 @@ def test_chatglm3_kernel_decode_step_compiles(mesh, monkeypatch):
         i32(4, -(-cfg.max_seq // 16)), i32(4), i32(4),
         name="fused_gemv_allreduce")
     # the served step's named scopes reach the chip compiler's metadata:
-    # the kernel under the MLP, the gather under paged attention
+    # the GEMV+AllReduce kernel under the MLP, the paged-attention kernel
+    # under paged attention
     assert re.search(r'op_name="[^"]*/mlp/[^"]*/fused_gemv_allreduce/'
                      r'[^"]*pallas_call"', text)
-    assert re.search(r'gather\([^\n]*op_name="[^"]*/attn\.paged/', text)
+    assert re.search(r'op_name="[^"]*/attn\.paged/[^"]*paged_attention/'
+                     r'[^"]*pallas_call"', text)
