@@ -5,9 +5,9 @@
 
 For each seed, in one process: the cell's set-up and window exactly as a
 benchmark run makes them, then the same sample of finished requests
-compared with the float32 reference, both as served by the program and
-as the float8 control would have chosen its tokens at the same
-positions.  One JSON line per seed: the widest gap of each, the numbers
+compared with the family's float32 reference, both as served by the
+program and as the float8 control would have chosen its tokens at the
+same positions.  One JSON line per seed: the widest gap of each, the numbers
 of requests and tokens compared, and the end-to-end metrics of the
 window.  The benchmark's own runs never run the control.
 """
@@ -29,28 +29,28 @@ def main(argv=None):
     ap.add_argument("--seeds", required=True)
     args = ap.parse_args(argv)
     sys.path[:0] = [str(ROOT), str(ROOT / "src")]
-    from bench import check, harness, reference
+    from bench import check, families, harness
     from bench.peaks import peaks_for
     from bench.run import enable_cache, read_metric, require_chips
-    from bench.work import Shape
 
     cell = harness.load_cell(args.workload)
     devices = require_chips(cell.chips)
     enable_cache()
-    shape = Shape.from_config(cell.config)
+    family, shape = families.load(cell.config)
     peaks = peaks_for(devices[0].device_kind)
     for seed in [int(s) for s in args.seeds.split(",")]:
         t = time.perf_counter()
         rec, served = harness.run(cell, seed, args.seconds, False,
-                                  t_start=t, shape=shape, peaks=peaks)
+                                  t_start=t, family=family, shape=shape,
+                                  peaks=peaks)
         metrics = {n: read_metric(n, rec)
                    for n in harness.cell_metrics(cell, False)}
         harness.release(served)
         del served
         picked = check.sample(rec.finished, seed)
-        g, gl = reference.gaps(seed, shape,
-                               [(r.prompt, r.tokens) for r in picked],
-                               control=True)
+        g, gl = family.gaps(seed, shape,
+                            [(r.prompt, r.tokens) for r in picked],
+                            control=True)
         print(json.dumps({
             "seed": seed,
             "program_max_gap": max(float(x.max()) for x in g),

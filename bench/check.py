@@ -3,9 +3,10 @@
 Once the window has closed and the program's state is freed, a sample of
 the requests the run finished, drawn from the seed and always holding
 the one with the most served tokens, is teacher-forced through the plain
-float32 reference (:mod:`bench.reference`).  At every position that
-produced a served token, the gap by which that token's logit lies below
-the reference's best is read; the widest gap over the sample is held to
+float32 reference of the configuration's model family (its ``gaps``,
+:mod:`bench.families`).  At every position that produced a served token,
+the gap by which that token's logit lies below the reference's best is
+read; the widest gap over the sample is held to
 the cell's limit (``bench/limits/<workload>.json``), which lies between
 what sound runs of the program read and what the float8 control reads.
 The run must also have served every request its full output, truncated

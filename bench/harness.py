@@ -105,6 +105,20 @@ class StepPrograms:
         return out
 
 
+def replace_fields(obj, overrides: dict):
+    """``obj`` with the fields ``overrides`` names replaced; a dict given
+    for a field that holds a dataclass replaces fields of that dataclass
+    (``{"moe": {"n_experts": 8}}``) and keeps the others."""
+    def value(name, new):
+        old = getattr(obj, name)
+        if isinstance(new, dict) and dataclasses.is_dataclass(old):
+            return replace_fields(old, new)
+        return new
+
+    return dataclasses.replace(obj, **{k: value(k, v)
+                                       for k, v in overrides.items()})
+
+
 def program_bundle(cell: Cell):
     """The registry bundle with the configuration's overrides, whose
     ``init_params`` draws the benchmark's seeded weights."""
@@ -116,8 +130,8 @@ def program_bundle(cell: Cell):
 
     h = cell.config["harness"]
     base = get_arch(h["arch"])
-    base = dataclasses.replace(base, config=dataclasses.replace(
-        base.config, **h.get("overrides", {})))
+    base = dataclasses.replace(base, config=replace_fields(
+        base.config, h.get("overrides", {})))
     struct, specs = split_params(jax.eval_shape(base.init_params,
                                                 jax.random.PRNGKey(0)))
     d_model = base.config.d_model
@@ -196,7 +210,8 @@ class Record:
     """What a run leaves for the metric readers and the check."""
     cell: Cell
     seed: int
-    shape: object
+    family: object                        # bench/families/<family>.py
+    shape: object                         # the family's Shape
     peaks: object
     chips: int
     chunk: int
@@ -246,7 +261,7 @@ class Compiles:
 
 
 def run(cell: Cell, seed: int, seconds: float, trace: bool, *, t_start,
-        shape, peaks, make_ctx=None, wrap_steps=None) -> tuple:
+        family, shape, peaks, make_ctx=None, wrap_steps=None) -> tuple:
     """Set up, warm up, serve the window; returns (record, served)."""
     import jax
     from jax.profiler import TraceAnnotation
@@ -261,7 +276,7 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, *, t_start,
     warm_up_shapes(served)
     eng = served.engine
     h = cell.config["harness"]
-    rec = Record(cell, seed, shape, peaks, cell.chips, h["chunk"])
+    rec = Record(cell, seed, family, shape, peaks, cell.chips, h["chunk"])
     mix = cell.mix
     gen = Generator(mix, seed, shape.vocab, eng.batch)
     clock = time.perf_counter

@@ -1,9 +1,10 @@
 """paged_attn_share: per cent of the step programs' device time on device
 0 in the traced window that ops under the ``attn.paged`` named scope take
-(``paged_attention``: the gather of the tables' blocks and the flash
-spans over them).  Each op is found by its HLO instruction in the step
-program it ran in (bench/scopes.py); a program without the scope reads
-nothing."""
+(``paged_attention``: on a TPU the Pallas kernel ``paged_attention``,
+which reads each slot's visible KV blocks in place, with the per-rank
+block list and the merge of partial results around it).  Each op is
+found by its HLO instruction in the step program it ran in
+(bench/scopes.py); a program without the scope reads nothing."""
 from bench.scopes import scope_map, scope_seconds, step_programs_hlo
 
 

@@ -1,8 +1,7 @@
 """step_mfu.decode: the whole step's share of the chips' bf16 peak in a
 decode cell: model FLOPs of every token the step programs processed in
-the traced window (bench/work.py) over the window's length times the
-chips' peak."""
-from bench.work import step_flops
+the traced window (the family's ``step_flops``) over the window's length
+times the chips' peak."""
 
 
 def read(rec):
@@ -11,7 +10,8 @@ def read(rec):
     spans = rec.trace.spans_named("bench.step_c")
     if not spans:
         return None
-    flops = sum(step_flops(rec.shape, rec.calls[s.stats["call"]][1])
+    flops = sum(rec.family.step_flops(rec.shape,
+                                      rec.calls[s.stats["call"]][1])
                 for s in spans)
     return 100.0 * flops / (rec.trace.window_s * rec.chips
                             * rec.peaks.flops_bf16)

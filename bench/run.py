@@ -5,12 +5,14 @@
 The cell (``BENCHMARK.json``) names a configuration
 (``bench/configs/<name>.json``) and a traffic mix
 (``bench/traffic/<name>.json``); each metric is read by
-``bench/metrics/<name>.py``.  Set-up draws the weights on the device from
-``--seed``, loads or compiles the two step programs, and warms up the
-traffic; then the window serves for ``--seconds``.  ``--trace 1`` profiles
+``bench/metrics/<name>.py``; the configuration's model family (its
+shape, work counts and reference) is ``bench/families/<family>.py``.
+Set-up draws the weights on the device from ``--seed``, loads or
+compiles the two step programs, and warms up the traffic; then the
+window serves for ``--seconds``.  ``--trace 1`` profiles
 a few seconds of the window and prints the per-layer metrics instead of
 the end-to-end ones.  After the window the served tokens are checked
-against the float32 reference.
+against the family's float32 reference.
 
 The last line of standard output is one JSON object (``correct``,
 ``attempted``, ``failed``, ``metrics``, ``device``, with ``--trace 1``
@@ -92,16 +94,15 @@ def execute(cell, seed: int, seconds: float, trace: bool, *, devices,
     check.  Returns the result object."""
     import jax
 
-    from bench import check, harness, reference
+    from bench import check, families, harness
     from bench.peaks import peaks_for
-    from bench.work import Shape
     from bench.xplane import busy_s, idle_gaps, op_seconds
 
-    shape = Shape.from_config(cell.config)
+    family, shape = families.load(cell.config)
     peaks = peaks or peaks_for(devices[0].device_kind)
     rec, served = harness.run(
-        cell, seed, seconds, trace, t_start=T_START, shape=shape,
-        peaks=peaks, make_ctx=make_ctx, wrap_steps=wrap_steps)
+        cell, seed, seconds, trace, t_start=T_START, family=family,
+        shape=shape, peaks=peaks, make_ctx=make_ctx, wrap_steps=wrap_steps)
     late = rec.lateness or [0.0]
     harness.log(f"window {rec.t1 - rec.t0:.3f} s, {len(rec.ticks)} ticks "
                 f"({len(rec.ticks) - rec.window_first_tick} in the window), "
@@ -131,7 +132,7 @@ def execute(cell, seed: int, seconds: float, trace: bool, *, devices,
     del served
     t = time.perf_counter()
     ok, rows, n_req, n_tok = check.check(
-        rec, limits, lambda seqs: reference.gaps(seed, shape, seqs)[0])
+        rec, limits, lambda seqs: family.gaps(seed, shape, seqs)[0])
     harness.log(f"reference: {n_req} requests, {n_tok} served tokens "
                 f"compared in {time.perf_counter() - t:.1f} s")
     out["correct"] = ok

@@ -30,22 +30,21 @@ def main(argv=None):
     ap.add_argument("--rates", required=True)
     args = ap.parse_args(argv)
     sys.path[:0] = [str(ROOT), str(ROOT / "src")]
-    from bench import harness
+    from bench import families, harness
     from bench.peaks import peaks_for
     from bench.run import enable_cache, read_metric, require_chips
-    from bench.work import Shape
 
     cell = harness.make_cell("sweep", args.config, args.traffic, 0, {})
     cell.chips = cell.config["harness"]["chips"]
     devices = require_chips(cell.chips)
     enable_cache()
-    shape = Shape.from_config(cell.config)
+    family, shape = families.load(cell.config)
     peaks = peaks_for(devices[0].device_kind)
     for rate in [float(r) for r in args.rates.split(",")]:
         c = dataclasses.replace(cell, mix=dict(cell.mix, rate=rate))
         rec, served = harness.run(c, args.seed, args.seconds, False,
-                                  t_start=time.perf_counter(), shape=shape,
-                                  peaks=peaks)
+                                  t_start=time.perf_counter(),
+                                  family=family, shape=shape, peaks=peaks)
         harness.release(served)
         del served
         due = sum(rec.t0 <= d <= rec.t1 for d in rec.due.values())

@@ -5,11 +5,10 @@ from pathlib import Path
 import numpy as np
 import pytest
 
-from bench import harness
+from bench import families, harness
 from bench.peaks import PEAKS
 from bench.run import read_metric
 from bench.tests.tiny import make_ctx, tiny_cell
-from bench.work import Shape
 
 CHILDREN = ["serve.schedule", "serve.dispatch", "serve.sample",
             "serve.commit"]
@@ -68,11 +67,12 @@ def test_counters_agree_with_the_harness():
     engine's way, over every tick of a short run; the tokens fed are
     those of the step calls the harness recorded."""
     cell = tiny_cell("chatglm3-6b")
+    family, shape = families.load(cell.config)
     engines = []
     rec, served = harness.run(
-        cell, 2147483652, 2.0, False, t_start=0.0,
-        shape=Shape.from_config(cell.config), peaks=PEAKS["TPU v5 lite"],
-        make_ctx=make_ctx(1), wrap_steps=lambda s: engines.append(s.engine))
+        cell, 2147483652, 2.0, False, t_start=0.0, family=family,
+        shape=shape, peaks=PEAKS["TPU v5 lite"], make_ctx=make_ctx(1),
+        wrap_steps=lambda s: engines.append(s.engine))
     st = engines[0].stats
     harness.release(served)
     widths = [c for _, _, c in rec.ticks if c]

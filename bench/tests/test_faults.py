@@ -5,6 +5,8 @@ the check against the reference with the cell's own limit) at a size the
 CPU holds, past the look for a chip, once sound and once for each fault
 a served cell can have.
 """
+import itertools
+
 import jax.numpy as jnp
 import pytest
 
@@ -53,10 +55,16 @@ def half_batch(fn):
 
 
 def token_altered(fn):
-    """Slot 0's logits are rotated by one, so its token is the next id."""
+    """One slot's logits per call are rotated by one, so its token is the
+    next id.  The slot moves on with every call, so every request of a
+    few tokens gets an altered one, whichever requests the check samples
+    (a fixed slot could hold none of them)."""
+    calls = itertools.count()
+
     def f(*args):
         logits, pool = fn(*args)
-        return logits.at[0].set(jnp.roll(logits[0], 1)), pool
+        i = next(calls) % logits.shape[0]
+        return logits.at[i].set(jnp.roll(logits[i], 1)), pool
     return f
 
 

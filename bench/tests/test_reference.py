@@ -3,9 +3,8 @@ paged decode through PagedDecodeEngine, for both RoPE styles."""
 import numpy as np
 import pytest
 
-from bench import harness, reference
+from bench import families, harness
 from bench.tests.tiny import make_ctx, tiny_cell
-from bench.work import Shape
 
 
 def serve(cell, seed, requests):
@@ -28,7 +27,7 @@ def serve(cell, seed, requests):
 ])
 def test_engine_agrees_with_reference(config, extra):
     cell = tiny_cell(config, dtype="float32", **extra)
-    shape = Shape.from_config(cell.config)
+    reference, shape = families.load(cell.config)
     rng = np.random.default_rng(3)
     # prompts of 1, 3, 8 and 21 tokens: decode only, a short chunk, one
     # whole chunk, and chunks of 8, 8 and 5; then 12-19 decoded tokens
@@ -44,7 +43,7 @@ def test_engine_agrees_with_reference(config, extra):
 
 def test_reference_tells_wrong_tokens_apart():
     cell = tiny_cell("chatglm3-6b", dtype="float32")
-    shape = Shape.from_config(cell.config)
+    reference, shape = families.load(cell.config)
     prompt = list(range(5, 25))
     tokens = serve(cell, 11, [(prompt, 10)])[0]
     wrong = [(t + 1) % shape.vocab for t in tokens]

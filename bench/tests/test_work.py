@@ -1,18 +1,23 @@
-"""Work counts from shapes, and the table of peaks."""
+"""Work counts from shapes (the gqa family's), least times, and the
+table of peaks."""
 import json
 from pathlib import Path
 
 import pytest
 
+from bench import families
 from bench import peaks as P
 from bench import work as W
 
 CONFIGS = Path(__file__).resolve().parents[1] / "configs"
+G = families.module("gqa")
 
 
 def shape(name):
-    return W.Shape.from_config(json.loads((CONFIGS / f"{name}.json")
-                                          .read_text()))
+    family, s = families.load(json.loads((CONFIGS / f"{name}.json")
+                                         .read_text()))
+    assert family is G
+    return s
 
 
 def test_chatglm3_parameters_and_weight_bytes():
@@ -30,38 +35,40 @@ def test_decode_step_bytes_follow_live_contexts():
     assert s.kv_bytes_per_token == kv_tok
     # two decoding slots at positions 99 and 9: contexts of 100 and 10
     # positions, one new K/V row each, one embedding row each
-    b = W.step_bytes(s, [(99, 1), (9, 1)])
+    b = G.step_bytes(s, [(99, 1), (9, 1)])
     assert b == s.weight_bytes + (100 + 1 + 10 + 1) * kv_tok + 2 * 4096 * 2
     # a longer context costs exactly its K/V, whatever the table holds
-    assert W.step_bytes(s, [(199, 1), (9, 1)]) - b == 100 * kv_tok
+    assert G.step_bytes(s, [(199, 1), (9, 1)]) - b == 100 * kv_tok
 
 
 def test_window_caps_the_context():
     s = shape("phi3-medium-14b")
     assert s.window == 2047
-    assert W.step_bytes(s, [(3000, 1)]) == W.step_bytes(s, [(2046, 1)])
-    assert W.token_flops(s, 3000) == W.token_flops(s, 2046)
-    assert W.token_flops(s, 100) < W.token_flops(s, 2046)
+    assert G.step_bytes(s, [(3000, 1)]) == G.step_bytes(s, [(2046, 1)])
+    assert G.token_flops(s, 3000) == G.token_flops(s, 2046)
+    assert G.token_flops(s, 100) < G.token_flops(s, 2046)
 
 
 def test_model_flops_per_token():
     s = shape("chatglm3-6b")
     per_layer = 4096 * (32 + 4) * 128 + 32 * 128 * 4096 + 3 * 4096 * 13696
     attn = 4 * 10 * 32 * 128                     # position 9: 10 keys
-    assert W.token_flops(s, 9) == 28 * (2 * per_layer + attn)
-    assert W.head_flops(s) == 2 * 4096 * 65024
+    assert G.token_flops(s, 9) == 28 * (2 * per_layer + attn)
+    assert G.head_flops(s) == 2 * 4096 * 65024
     # a 3-token prefill chunk from position 5 and one decode at 9
-    assert W.step_flops(s, [(5, 3), (9, 1)]) == pytest.approx(
-        sum(W.token_flops(s, p) for p in (5, 6, 7, 9)) + 2 * W.head_flops(s))
+    assert G.step_flops(s, [(5, 3), (9, 1)]) == pytest.approx(
+        sum(G.token_flops(s, p) for p in (5, 6, 7, 9)) + 2 * G.head_flops(s))
 
 
 def test_least_step_time_is_bound_by_hbm_at_decode():
     s = shape("chatglm3-6b")
     pk = P.peaks_for("TPU v5 lite")
-    t, bound = W.step_least_seconds(s, [(500, 1)] * 32, 1, pk)
+    slots = [(500, 1)] * 32
+    work = G.step_flops(s, slots), G.step_bytes(s, slots)
+    t, bound = W.step_least_seconds(*work, 1, pk)
     assert bound == "hbm"
-    assert t == pytest.approx(W.step_bytes(s, [(500, 1)] * 32) / 819e9)
-    t4, _ = W.step_least_seconds(s, [(500, 1)] * 32, 4, pk)
+    assert t == pytest.approx(G.step_bytes(s, slots) / 819e9)
+    t4, _ = W.step_least_seconds(*work, 4, pk)
     assert t4 == pytest.approx(t / 4)
 
 

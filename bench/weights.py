@@ -6,10 +6,14 @@ in one jitted call on the device, in the served dtype, and the reference
 can draw one layer at a time and get the same values.
 
 Names follow the program's parameter tree (``layers/l0/attn/w_qkv``,
-``embed/table``, ...).  Matrices are normal with standard deviation
-fan_in ** -0.5, the embedding table (also the LM head) d_model ** -0.5,
-so logits have a spread of about 1; norm scales are 1 + 0.1 normal, so a
-norm whose scale is dropped shows.
+``embed/table``, ...).  The rule goes by rank, not by name, so that a
+new family's leaves need none of their own: every vector (a norm's
+scale, a qk-norm's) is 1 + 0.1 normal, so a norm whose scale is dropped
+shows; the embedding table (also the LM head) is normal with standard
+deviation d_model ** -0.5, so logits have a spread of about 1; every
+other leaf, expert stacks [E, d, f] and [E, f, d] among them, is normal
+with standard deviation fan_in ** -0.5, its fan-in the second-to-last
+axis.
 """
 from __future__ import annotations
 
@@ -30,7 +34,7 @@ def leaf_key(root, name: str):
 def draw(key, name: str, shape, d_model: int):
     """float32 values of one weight (one layer's slice for stacked ones)."""
     x = jax.random.normal(key, shape, jnp.float32)
-    if name.endswith(("ln1", "ln2", "final_norm")):
+    if len(shape) == 1:
         return 1.0 + 0.1 * x
     if name == "embed/table":
         return x * d_model ** -0.5
